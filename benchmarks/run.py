@@ -18,6 +18,7 @@ import sys
 import time
 
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = [
     "recall_sparsity",  # Fig. 6a + Table 1 + Fig. 5
@@ -38,6 +39,7 @@ def main() -> None:
                     help="kernel backend for dispatched ops "
                          "(default: platform-appropriate)")
     args = ap.parse_args()
+    enable_compile_cache()
     suites = args.only.split(",") if args.only else SUITES
     if args.backend:
         dispatch.set_default_backend(args.backend)

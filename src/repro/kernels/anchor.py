@@ -26,10 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.config import AnchorConfig
 from repro.kernels import dispatch
-from repro.kernels.indexing import kv_head_index, length_grid_operand
+from repro.kernels.indexing import kv_head_index, prefetch_lengths
 
 _NEG_INF = -1e30
 
@@ -42,11 +41,12 @@ def _candidate_block(i, w, cfg: AnchorConfig):
 
 
 def _anchor_kernel(
-    q_ref, k_ref, len_ref, qm_ref, mb_ref, ms_ref,
-    *, cfg: AnchorConfig, scale: float, t_n: int
+    len_ref, q_ref, k_ref, qm_ref, mb_ref, ms_ref,
+    *, cfg: AnchorConfig, scale: float, t_n: int, heads: int
 ):
     i = pl.program_id(1)
     w = pl.program_id(2)
+    length = len_ref[pl.program_id(0) // heads]
 
     @pl.when(w == 0)
     def _init():
@@ -65,7 +65,6 @@ def _anchor_kernel(
         ) * scale
         row = i * cfg.block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = blk * cfg.block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        length = len_ref[0, 0]
         s = jnp.where((col <= row) & (col < length) & (row < length),
                       s, _NEG_INF)
         ms_ref[...] = jnp.maximum(
@@ -77,19 +76,19 @@ def _anchor_kernel(
         # block means cost nothing extra and nothing row-resolution ever
         # leaves the kernel.  Padded rows (varlen) are excluded; an
         # all-padding block pools to m_bar = +inf (never selected) and
-        # q_mean = 0.
-        length = len_ref[0, 0]
+        # q_mean = 0.  Every reduction keeps its (1, ·) shape: the pooled
+        # values are stored as one-row tiles, never as scalars.
         rows = i * cfg.block_q + jax.lax.broadcasted_iota(
             jnp.int32, (cfg.block_q, 1), 0)
         rv = rows < length  # (block_q, 1)
-        cnt = jnp.sum(rv.astype(jnp.float32))
-        denom = jnp.maximum(cnt, 1.0)
-        m_sum = jnp.sum(jnp.where(rv, ms_ref[...], 0.0))
-        mb_ref[0] = jnp.where(
-            cnt == 0.0, jnp.full((1,), jnp.inf, jnp.float32),
-            (m_sum / denom)[None])
+        cnt = jnp.sum(rv.astype(jnp.float32), axis=0, keepdims=True)
+        denom = jnp.maximum(cnt, 1.0)  # (1, 1)
+        m_sum = jnp.sum(jnp.where(rv, ms_ref[...], 0.0), axis=0,
+                        keepdims=True)
+        mb_ref[0, 0] = jnp.where(cnt == 0.0, jnp.inf, m_sum / denom)
         q = q_ref[0].astype(jnp.float32)
-        qm_ref[0, 0] = jnp.sum(jnp.where(rv, q, 0.0), axis=0) / denom
+        qm_ref[0, 0] = jnp.sum(jnp.where(rv, q, 0.0), axis=0,
+                               keepdims=True) / denom
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
@@ -97,7 +96,8 @@ def anchor_phase_pallas(
     q: jnp.ndarray,
     k: jnp.ndarray,
     cfg: AnchorConfig,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     lengths: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Alg. 1 (scores-only) for batched heads.
@@ -117,37 +117,52 @@ def anchor_phase_pallas(
 
     qf = q.reshape(batch * hq, n, d)
     kf = k.reshape(batch * hkv, n, d)
-    lf, len_spec = length_grid_operand(lengths, batch, hq, n)
+    lens = prefetch_lengths(lengths, batch, n)
 
-    def kv_index(b, i, w):
+    def q_index(b, i, w, len_ref):
+        del w, len_ref
+        return b, i, 0
+
+    def kv_index(b, i, w, len_ref):
+        del len_ref
         blk = jnp.clip(_candidate_block(i, w, cfg), 0, t_n - 1)
         return kv_head_index(b, hq, hkv), blk, 0
 
-    kernel = functools.partial(_anchor_kernel, cfg=cfg, scale=scale, t_n=t_n)
-    q_mean, m_bar = pl.pallas_call(
-        kernel,
+    def pooled_index(b, i, w, len_ref):
+        del w, len_ref
+        return b, i, 0, 0
+
+    kernel = functools.partial(
+        _anchor_kernel, cfg=cfg, scale=scale, t_n=t_n, heads=hq)
+    # The pooled outputs carry a unit row axis so each grid step writes a
+    # whole (1, D) / (1, 1) trailing tile.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(batch * hq, t_m, n_slots),
         in_specs=[
-            pl.BlockSpec((1, cfg.block_q, d), lambda b, i, w: (b, i, 0)),
+            pl.BlockSpec((1, cfg.block_q, d), q_index),
             pl.BlockSpec((1, cfg.block_kv, d), kv_index),
-            len_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, i, w: (b, i, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, w: (b, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch * hq, t_m, d), jnp.float32),
-            jax.ShapeDtypeStruct((batch * hq, t_m), jnp.float32),
+            pl.BlockSpec((1, 1, 1, d), pooled_index),
+            pl.BlockSpec((1, 1, 1, 1), pooled_index),
         ],
         scratch_shapes=[
             pltpu.VMEM((cfg.block_q, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    q_mean, m_bar = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((batch * hq, t_m, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((batch * hq, t_m, 1, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qf, kf, lf)
+    )(lens, qf, kf)
     return (q_mean.reshape(batch, hq, t_m, d),
             m_bar.reshape(batch, hq, t_m))
 

@@ -6,7 +6,7 @@ scratch — the kernel twin of the blockwise XLA path introduced in §Perf
 iteration A3 (scores never touch HBM).
 
 Grid: ``(batch*heads, S // block_s)`` with the cache axis sequential.
-``cache_len`` arrives as a scalar operand (replicated (1,1) block).
+``cache_len`` arrives via scalar prefetch (SMEM).
 """
 
 from __future__ import annotations
@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.kernels import dispatch
 from repro.kernels.indexing import kv_head_index
 
@@ -40,8 +39,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, ms_ref, ls_ref,
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (1, block_s)
+    cache_len = len_ref[0]
     col = j * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = col < len_ref[0, 0]
+    valid = col < cache_len
     s = jnp.where(valid, s, _NEG_INF)
     m_prev = ms_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -51,7 +51,10 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, ms_ref, ls_ref,
     ls_ref[...] = ls_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     # Zero masked V rows too: a grid padded with a partial tail block reads
     # garbage (possibly NaN) beyond s_len, and 0 * NaN would poison acc.
-    v = jnp.where(valid[0][:, None], v_ref[0].astype(jnp.float32), 0.0)
+    # The row mask is built in V's own (block_s, D) layout.
+    v = v_ref[0].astype(jnp.float32)
+    v_row = j * block_s + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    v = jnp.where(v_row < cache_len, v, 0.0)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -70,7 +73,8 @@ def flash_decode(
     v_cache: jnp.ndarray,
     cache_len: jnp.ndarray,
     block_s: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """One-token decode attention.
 
@@ -90,29 +94,37 @@ def flash_decode(
     qf = q.reshape(b * hq, 1, d)
     kf = k_cache.reshape(b * hkv, s_len, d)
     vf = v_cache.reshape(b * hkv, s_len, d)
-    len_arr = jnp.full((1, 1), cache_len, jnp.int32)
+    len_arr = jnp.full((1,), cache_len, jnp.int32)
 
-    def kv_index(bh, j):
+    def q_index(bh, j, len_ref):
+        del j, len_ref
+        return bh, 0, 0
+
+    def kv_index(bh, j, len_ref):
+        del len_ref
         return kv_head_index(bh, hq, hkv), j, 0
 
     kernel = functools.partial(_decode_kernel, block_s=block_s, scale=scale)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b * hq, num_s_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, j: (0, 0)),
-            pl.BlockSpec((1, 1, d), lambda bh, j: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, d), q_index),
             pl.BlockSpec((1, block_s, d), kv_index),
             pl.BlockSpec((1, block_s, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bh, j: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, d), q_index),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(len_arr, qf, kf, vf)
@@ -175,7 +187,8 @@ def paged_flash_decode(
     v_pages: jnp.ndarray,
     page_tables: jnp.ndarray,
     cache_len: jnp.ndarray,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """One-token decode attention over a *paged* KV cache.
 
@@ -230,7 +243,7 @@ def paged_flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pt, len_arr, qf, k_pages, v_pages)

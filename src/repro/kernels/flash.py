@@ -8,8 +8,8 @@ head dim (128 or 256 for every assigned arch ⇒ lane-aligned).
 GQA is handled in the K/V index maps (``kv_head = q_head // group``) so
 grouped KV is never replicated in HBM.
 
-Variable-length batches: an optional per-sequence ``lengths`` operand (one
-int32 per flattened batch*head row) tightens the causal mask to
+Variable-length batches: the per-sequence ``lengths`` arrive via scalar
+prefetch (one int32 per batch row, in SMEM) and tighten the causal mask to
 ``col <= row < length`` — padding keys contribute nothing and padded query
 rows emit exact zeros (their normalizer is 0; the final divide is guarded).
 """
@@ -23,19 +23,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.kernels import dispatch
-from repro.kernels.indexing import kv_head_index, length_grid_operand
+from repro.kernels.indexing import kv_head_index, prefetch_lengths
 
 _NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, block_q, block_kv, scale
+    len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+    *, block_q, block_kv, scale, heads
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
+    length = len_ref[pl.program_id(0) // heads]
 
     @pl.when(j == 0)
     def _init():
@@ -53,7 +53,6 @@ def _flash_kernel(
         ) * scale
         row = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        length = len_ref[0, 0]
         s = jnp.where((col <= row) & (col < length) & (row < length),
                       s, _NEG_INF)
         m_prev = m_ref[...]
@@ -86,7 +85,8 @@ def flash_attention(
     v: jnp.ndarray,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     lengths: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Causal flash attention.  q: (B, Hq, N, D); k, v: (B, Hkv, N, D).
@@ -103,36 +103,43 @@ def flash_attention(
     qf = q.reshape(batch * hq, n, d)
     kf = k.reshape(batch * hkv, n, d)
     vf = v.reshape(batch * hkv, n, d)
-    lf, len_spec = length_grid_operand(lengths, batch, hq, n)
+    lens = prefetch_lengths(lengths, batch, n)
 
-    def kv_index(b, i, j):
-        del i
+    def q_index(b, i, j, len_ref):
+        del j, len_ref
+        return b, i, 0
+
+    def kv_index(b, i, j, len_ref):
+        del i, len_ref
         return kv_head_index(b, hq, hkv), j, 0
 
     kernel = functools.partial(
-        _flash_kernel, block_q=block_q, block_kv=block_kv, scale=scale
-    )
-    out = pl.pallas_call(
-        kernel,
+        _flash_kernel, block_q=block_q, block_kv=block_kv, scale=scale,
+        heads=hq)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(batch * hq, t_m, t_n),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d), q_index),
             pl.BlockSpec((1, block_kv, d), kv_index),
             pl.BlockSpec((1, block_kv, d), kv_index),
-            len_spec,
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch * hq, n, d), q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, d), q_index),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((batch * hq, n, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qf, kf, vf, lf)
+    )(lens, qf, kf, vf)
     return out.reshape(batch, hq, n, d)
 
 

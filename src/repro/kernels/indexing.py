@@ -33,7 +33,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 
 class StripeIndex(NamedTuple):
@@ -85,24 +84,18 @@ def stripe_tile(n: int, block_c: int) -> int:
     return math.gcd(n, max(1, block_c))
 
 
-def length_grid_operand(lengths, batch: int, heads: int, n: int):
-    """Per-sequence lengths → one ``(1, 1)`` SMEM-style row per grid row.
+def prefetch_lengths(lengths, batch: int, n: int) -> jnp.ndarray:
+    """Per-sequence valid-token counts as a ``(batch,)`` int32 operand.
 
     The one piece of varlen plumbing shared by every Pallas kernel in
-    this package (flash / anchor / stripe-select): flatten the optional
-    ``(B,)`` valid-token counts to a ``(batch*heads, 1)`` int32 operand
-    (``lengths=None`` ⇒ every row is fully valid) and pair it with the
-    ``(1, 1)`` BlockSpec whose index map picks grid row ``b``'s entry
-    regardless of the grid's remaining axes.
-
-    Returns ``(operand, block_spec)``.
+    this package: the array rides in as a scalar-prefetch operand
+    (``PrefetchScalarGridSpec``), so kernels read ``len_ref[b]`` from
+    SMEM for their batch row ``b`` (``lengths=None`` ⇒ every row is
+    fully valid).
     """
     if lengths is None:
-        lens = jnp.full((batch,), n, jnp.int32)
-    else:
-        lens = lengths.astype(jnp.int32)
-    operand = jnp.repeat(lens, heads)[:, None]  # (batch*heads, 1)
-    return operand, pl.BlockSpec((1, 1), lambda b, *_: (b, 0))
+        return jnp.full((batch,), n, jnp.int32)
+    return lengths.astype(jnp.int32)
 
 
 def select_capacity(n_tiles: int, n: int, capacity: int | None,

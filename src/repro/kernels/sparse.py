@@ -33,17 +33,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.config import AnchorConfig
 from repro.kernels import dispatch
-from repro.kernels.indexing import StripeIndex
+from repro.kernels.indexing import StripeIndex, prefetch_lengths
 
 _NEG_INF = -1e30
 
 
+def _slot_index(bh, i, c, cfg: AnchorConfig, t_s: int, c_t: int):
+    """Flat position of tile slot ``c`` of query block ``i``'s superblock
+    in the 1-D scalar-prefetched tile table (1-D keeps SMEM unpadded)."""
+    return (bh * t_s + i // cfg.step) * c_t + c
+
+
 def _sparse_kernel(
     idx_ref, len_ref, off_ref, q_ref, k_ref, v_ref, valid_ref,
-    o_ref, ms_ref, ls_ref, accs_ref, *, cfg: AnchorConfig, scale, g, tile
+    o_ref, ms_ref, ls_ref, accs_ref, *, cfg: AnchorConfig, scale, g, tile,
+    t_s, c_t, kv_heads
 ):
     bh = pl.program_id(0)
     i = pl.program_id(1)
@@ -63,16 +69,16 @@ def _sparse_kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (G*block_q, tile)
     # Per-query-head stripe validity of this tile slot: (G, tile) -> rows.
-    vld = valid_ref[0, :, 0] != 0
-    ok = jnp.broadcast_to(vld[:, None, :], (g, block_q, vld.shape[-1]))
-    ok = ok.reshape(rows, vld.shape[-1])
+    vld = valid_ref[0, 0, 0]
+    ok = jnp.broadcast_to(vld[:, None, :], (g, block_q, tile))
+    ok = ok.reshape(rows, tile) != 0
     # Causal + varlen trim from global positions: the row offset comes in
     # via scalar prefetch (chunked prefill sets it to the chunk start).
-    tile_id = idx_ref[bh, i // cfg.step, c]
+    tile_id = idx_ref[_slot_index(bh, i, c, cfg, t_s, c_t)]
     col = tile_id * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     row = (off_ref[0] + i * block_q
            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % block_q)
-    length = len_ref[bh]
+    length = len_ref[bh // kv_heads]
     ok &= (col <= row) & (col < length) & (row < length)
     s = jnp.where(ok, s, _NEG_INF)
     m_prev = ms_ref[...]
@@ -110,7 +116,8 @@ def sparse_attention_pallas(
     lengths: jnp.ndarray | None = None,
     q_offset: jnp.ndarray | None = None,
     block_c: int | None = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Alg. 3 (fused) for batched heads, index-driven.
 
@@ -143,13 +150,12 @@ def sparse_attention_pallas(
     qf = q.reshape(batch * hkv, g, n, d)
     kf = k.reshape(batch * hkv, nk, d)
     vf = v.reshape(batch * hkv, nk, dv)
-    validf = tables.valid.reshape(batch * hkv, g, t_s, c_t * tile)
-    idxf = tables.tile_idx.reshape(batch * hkv, t_s, c_t).astype(jnp.int32)
-    if lengths is None:
-        lens = jnp.full((batch,), nk, jnp.int32)
-    else:
-        lens = lengths.astype(jnp.int32)
-    lensf = jnp.repeat(lens, hkv)  # one entry per batch*Hkv grid row
+    # Kernel layout of the validity rows: one (G, tile) trailing tile per
+    # (KV row, superblock, slot).
+    validf = tables.valid.reshape(batch * hkv, g, t_s, c_t, tile).transpose(
+        0, 2, 3, 1, 4)
+    idxf = tables.tile_idx.reshape(-1).astype(jnp.int32)
+    lens = prefetch_lengths(lengths, batch, nk)
     offf = (jnp.zeros((1,), jnp.int32) if q_offset is None
             else jnp.asarray(q_offset, jnp.int32).reshape(1))
 
@@ -159,14 +165,15 @@ def sparse_attention_pallas(
 
     def kv_index(bh, i, c, idx_ref, len_ref, off_ref):
         del len_ref, off_ref
-        return bh, idx_ref[bh, i // cfg.step, c], 0
+        return bh, idx_ref[_slot_index(bh, i, c, cfg, t_s, c_t)], 0
 
     def valid_index(bh, i, c, idx_ref, len_ref, off_ref):
         del idx_ref, len_ref, off_ref
-        return bh, 0, i // cfg.step, c
+        return bh, i // cfg.step, c, 0, 0
 
     kernel = functools.partial(
-        _sparse_kernel, cfg=cfg, scale=scale, g=g, tile=tile)
+        _sparse_kernel, cfg=cfg, scale=scale, g=g, tile=tile, t_s=t_s,
+        c_t=c_t, kv_heads=hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(batch * hkv, t_m, c_t),
@@ -174,7 +181,7 @@ def sparse_attention_pallas(
             pl.BlockSpec((1, g, cfg.block_q, d), q_index),
             pl.BlockSpec((1, tile, d), kv_index),
             pl.BlockSpec((1, tile, dv), kv_index),
-            pl.BlockSpec((1, g, 1, tile), valid_index),
+            pl.BlockSpec((1, 1, 1, g, tile), valid_index),
         ],
         out_specs=pl.BlockSpec((1, g, cfg.block_q, dv), q_index),
         scratch_shapes=[
@@ -187,11 +194,11 @@ def sparse_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch * hkv, g, n, dv), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(idxf, lensf, offf, qf, kf, vf, validf)
+    )(idxf, lens, offf, qf, kf, vf, validf)
     return out.reshape(batch, hq, n, dv)
 
 

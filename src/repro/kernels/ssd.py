@@ -20,11 +20,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.kernels import dispatch
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref):
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref, h_ref):
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -33,7 +32,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref):
 
     x = x_ref[0].astype(jnp.float32)  # (chunk, P)
     dt = dt_ref[0].astype(jnp.float32)  # (chunk,)
-    a = a_ref[0, 0].astype(jnp.float32)  # scalar (negative)
+    a = a_ref[pl.program_id(0)]  # scalar (negative), from SMEM
     b = b_ref[0].astype(jnp.float32)  # (chunk, S)
     c = c_ref[0].astype(jnp.float32)  # (chunk, S)
 
@@ -84,7 +83,8 @@ def ssd_chunked(
     b: jnp.ndarray,
     c: jnp.ndarray,
     chunk: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Chunked SSD scan for batched heads.
 
@@ -99,30 +99,35 @@ def ssd_chunked(
     s = b.shape[-1]
     assert l % chunk == 0, (l, chunk)
 
-    y, h = pl.pallas_call(
-        _ssd_kernel,
+    # The per-head decay rides in via scalar prefetch; every index map
+    # takes (and ignores) its SMEM ref.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bh, l // chunk),
         in_specs=[
-            pl.BlockSpec((1, chunk, p), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, chunk), lambda i, t: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, chunk, s), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, chunk, s), lambda i, t: (i, t, 0)),
+            pl.BlockSpec((1, chunk, p), lambda i, t, a_ref: (i, t, 0)),
+            pl.BlockSpec((1, chunk), lambda i, t, a_ref: (i, t)),
+            pl.BlockSpec((1, chunk, s), lambda i, t, a_ref: (i, t, 0)),
+            pl.BlockSpec((1, chunk, s), lambda i, t, a_ref: (i, t, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, p), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, s, p), lambda i, t: (i, 0, 0)),
+            pl.BlockSpec((1, chunk, p), lambda i, t, a_ref: (i, t, 0)),
+            pl.BlockSpec((1, s, p), lambda i, t, a_ref: (i, 0, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM((s, p), jnp.float32)],
+    )
+    y, h = pl.pallas_call(
+        _ssd_kernel,
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, l, p), x.dtype),
             jax.ShapeDtypeStruct((bh, s, p), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((s, p), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(x, dt, a.reshape(bh, 1), b, c)
+    )(a.astype(jnp.float32), x, dt, b, c)
     return y, h
 
 

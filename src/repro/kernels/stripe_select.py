@@ -28,24 +28,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.config import AnchorConfig
 from repro.kernels import dispatch
 from repro.kernels.indexing import (
     StripeIndex,
-    length_grid_operand,
+    prefetch_lengths,
     select_capacity,
     window_start_tokens,
 )
 
 
 def _select_kernel(
-    qm_ref, mb_ref, k_ref, len_ref, tidx_ref, tvalid_ref, valid_ref,
-    counts_ref, hits_ref, kept_ref, slots_ref,
-    *, cfg: AnchorConfig, scale, tile, cap_s, c_sel, g
+    len_ref, qm_ref, mb_ref, k_ref, tidx_ref, tvalid_ref, valid_ref,
+    counts_ref, hits_ref, kept_ref, slot_ref,
+    *, cfg: AnchorConfig, scale, tile, cap_s, c_sel, g, kv_heads
 ):
     s_idx = pl.program_id(1)
     j = pl.program_id(2)
+    length = len_ref[pl.program_id(0) // kv_heads]
 
     @pl.when(j == 0)
     def _init():
@@ -54,53 +54,57 @@ def _select_kernel(
         valid_ref[...] = jnp.zeros_like(valid_ref)
         hits_ref[...] = jnp.zeros_like(hits_ref)
         kept_ref[...] = jnp.zeros_like(kept_ref)
-        slots_ref[...] = jnp.zeros_like(slots_ref)
+        slot_ref[0] = 0
 
     w_start = window_start_tokens(s_idx, cfg)
     in_candidate = ((j + 1) * tile > cfg.block_kv) & (j * tile < w_start)
 
     @pl.when(in_candidate)
     def _compute():
-        qm = qm_ref[0].astype(jnp.float32).reshape(g * cfg.step, -1)
-        kt = k_ref[0].astype(jnp.float32)  # (tile, d)
+        qm = qm_ref[0, 0]  # (G*step, D) f32
+        kt = k_ref[0].astype(jnp.float32)  # (tile, D)
         s = jax.lax.dot_general(
             qm, kt, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        mb = mb_ref[0].reshape(g * cfg.step)[:, None]
-        hit = ((mb - s) <= cfg.theta).reshape(g, cfg.step, tile).any(axis=1)
+        close = ((mb_ref[0, 0] - s) <= cfg.theta).astype(jnp.int32)
+        hit_i = jnp.max(close.reshape(g, cfg.step, tile), axis=1)  # (G, tile)
         col = j * tile + jax.lax.broadcasted_iota(jnp.int32, (g, tile), 1)
-        hit &= (col >= cfg.block_kv) & (col < w_start)
-        # Padding keys of a right-padded batch are never stripe-selected.
-        hit &= col < len_ref[0, 0]
+        # Stripe candidates only; padding keys of a right-padded batch are
+        # never stripe-selected.
+        ok = (col >= cfg.block_kv) & (col < w_start) & (col < length)
+        hit_i = jnp.where(ok, hit_i, 0)
         if cfg.share_kv_groups:
-            hit = jnp.broadcast_to(hit.any(axis=0, keepdims=True), hit.shape)
-        hit_i = hit.astype(jnp.int32)
+            hit_i = jnp.broadcast_to(
+                jnp.max(hit_i, axis=0, keepdims=True), hit_i.shape)
         # Position-ascending per-head budget: global rank = hits seen in
         # earlier tiles + the exclusive in-tile prefix.
-        rank = hits_ref[...] + jnp.cumsum(hit_i, axis=1) - hit_i
-        kept = hit & (rank < cap_s)
-        kept_i = kept.astype(jnp.int32)
+        # The in-tile prefix is a matmul with an upper-triangular ones
+        # matrix (Mosaic has no cumsum); 0/1 operands keep it exact.
+        tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+               <= jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
+        incl = jax.lax.dot_general(
+            hit_i.astype(jnp.float32), tri.astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        rank = hits_ref[...] + incl.astype(jnp.int32) - hit_i
+        kept_i = jnp.where(rank < cap_s, hit_i, 0)
         hits_ref[...] += jnp.sum(hit_i, axis=1, keepdims=True)
         kept_ref[...] += jnp.sum(kept_i, axis=1, keepdims=True)
 
-        # In-kernel compaction: scatter this tile into the next free slot.
-        slot = slots_ref[0, 0]
-        take = jnp.any(kept) & (slot < c_sel)
-        slot_eq = (jax.lax.broadcasted_iota(jnp.int32, (1, c_sel), 1)
-                   == slot) & take
-        tidx_ref[0] = jnp.where(slot_eq, j, tidx_ref[0])
-        tvalid_ref[0] = jnp.where(slot_eq, 1, tvalid_ref[0])
-        colslot = jax.lax.broadcasted_iota(
-            jnp.int32, (g, c_sel * tile), 1) // tile
-        kept_rep = jnp.broadcast_to(
-            kept_i[:, None, :], (g, c_sel, tile)).reshape(g, c_sel * tile)
-        valid_ref[0, :, 0] = jnp.where(
-            (colslot == slot) & take, kept_rep, valid_ref[0, :, 0])
-        slots_ref[0, 0] = slot + take.astype(jnp.int32)
+        # In-kernel compaction: a tile with any kept row takes the next
+        # free slot.
+        slot = slot_ref[0]
+
+        @pl.when((jnp.max(kept_i) > 0) & (slot < c_sel))
+        def _take():
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, c_sel), 1) == slot
+            tidx_ref[0, 0] = jnp.where(lane, j, tidx_ref[0, 0])
+            tvalid_ref[0, 0] = jnp.where(lane, 1, tvalid_ref[0, 0])
+            valid_ref[0, 0, slot] = kept_i
+            slot_ref[0] = slot + 1
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        counts_ref[0, :, 0] = kept_ref[...][:, 0]
+        counts_ref[0, 0] = kept_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "tile", "interpret"))
@@ -110,7 +114,8 @@ def stripe_select_pallas(
     k: jnp.ndarray,
     cfg: AnchorConfig,
     tile: int,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     lengths: jnp.ndarray | None = None,
 ) -> tuple[StripeIndex, jnp.ndarray]:
     """Alg. 2 (compact) for batched heads.
@@ -148,55 +153,72 @@ def stripe_select_pallas(
         m_bar = jnp.pad(m_bar, ((0, 0), (0, 0), (0, pad)),
                         constant_values=jnp.inf)
 
-    qf = q_mean.reshape(batch, hkv, g, t_s, cfg.step, d).reshape(
-        batch * hkv, g, t_s * cfg.step, d)
-    mf = m_bar.reshape(batch, hkv, g, t_s, cfg.step).reshape(
-        batch * hkv, g, t_s * cfg.step)
+    # Kernel layout: one (G*step, ·) slab per (KV row, superblock), so the
+    # pooled rows of a whole query group form the trailing tile.
+    qf = q_mean.reshape(batch * hkv, g, t_s, cfg.step, d).transpose(
+        0, 2, 1, 3, 4).reshape(batch * hkv, t_s, g * cfg.step, d)
+    mf = m_bar.reshape(batch * hkv, g, t_s, cfg.step).transpose(
+        0, 2, 1, 3).reshape(batch * hkv, t_s, g * cfg.step, 1)
     kf = k.reshape(batch * hkv, n, d)
-    lf, len_spec = length_grid_operand(lengths, batch, hkv, n)
+    lens = prefetch_lengths(lengths, batch, n)
+
+    def sb_index(b, s, j, len_ref):
+        del j, len_ref
+        return b, s, 0, 0
+
+    def k_index(b, s, j, len_ref):
+        del s, len_ref
+        return b, j, 0
+
+    def valid_index(b, s, j, len_ref):
+        del j, len_ref
+        return b, s, 0, 0, 0
 
     kernel = functools.partial(
         _select_kernel, cfg=cfg, scale=scale, tile=tile, cap_s=cap_s,
-        c_sel=c_sel, g=g)
-    tidx, tvalid, valid, counts = pl.pallas_call(
-        kernel,
+        c_sel=c_sel, g=g, kv_heads=hkv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(batch * hkv, t_s, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, g, cfg.step, d), lambda b, s, j: (b, 0, s, 0)),
-            pl.BlockSpec((1, g, cfg.step), lambda b, s, j: (b, 0, s)),
-            pl.BlockSpec((1, tile, d), lambda b, s, j: (b, j, 0)),
-            len_spec,
+            pl.BlockSpec((1, 1, g * cfg.step, d), sb_index),
+            pl.BlockSpec((1, 1, g * cfg.step, 1), sb_index),
+            pl.BlockSpec((1, tile, d), k_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, c_sel), lambda b, s, j: (b, s, 0)),
-            pl.BlockSpec((1, 1, c_sel), lambda b, s, j: (b, s, 0)),
-            pl.BlockSpec((1, g, 1, c_sel * tile),
-                         lambda b, s, j: (b, 0, s, 0)),
-            pl.BlockSpec((1, g, 1), lambda b, s, j: (b, 0, s)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch * hkv, t_s, c_sel), jnp.int32),
-            jax.ShapeDtypeStruct((batch * hkv, t_s, c_sel), jnp.int32),
-            jax.ShapeDtypeStruct((batch * hkv, g, t_s, c_sel * tile),
-                                 jnp.int32),
-            jax.ShapeDtypeStruct((batch * hkv, g, t_s), jnp.int32),
+            pl.BlockSpec((1, 1, 1, c_sel), sb_index),
+            pl.BlockSpec((1, 1, 1, c_sel), sb_index),
+            pl.BlockSpec((1, 1, c_sel, g, tile), valid_index),
+            pl.BlockSpec((1, 1, g, 1), sb_index),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.int32),
             pltpu.VMEM((g, 1), jnp.int32),
-            pltpu.VMEM((1, 1), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    tidx, tvalid, valid, counts = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((batch * hkv, t_s, 1, c_sel), jnp.int32),
+            jax.ShapeDtypeStruct((batch * hkv, t_s, 1, c_sel), jnp.int32),
+            jax.ShapeDtypeStruct((batch * hkv, t_s, c_sel, g, tile),
+                                 jnp.int32),
+            jax.ShapeDtypeStruct((batch * hkv, t_s, g, 1), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qf, mf, kf, lf)
+    )(lens, qf.astype(jnp.float32), mf.astype(jnp.float32), kf)
     tables = StripeIndex(
         tidx.reshape(batch, hkv, t_s, c_sel),
         tvalid.reshape(batch, hkv, t_s, c_sel),
-        valid.reshape(batch, hkv, g, t_s, c_sel * tile),
+        valid.transpose(0, 3, 1, 2, 4).reshape(
+            batch, hkv, g, t_s, c_sel * tile),
     )
-    return tables, counts.reshape(batch, hq, t_s)
+    return tables, counts.transpose(0, 2, 1, 3).reshape(batch, hq, t_s)
 
 
 dispatch.register("stripe_select", "pallas_interpret")(

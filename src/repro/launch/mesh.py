@@ -8,7 +8,16 @@ device-count override ordering.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """A device mesh whose axes are all ``Auto`` (GSPMD-partitioned).
+
+    ``jax.make_mesh`` otherwise makes ``Explicit`` axes, under which a
+    gather from a sharded array needs an explicit out-sharding.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,12 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Small mesh for subprocess tests (8 host devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_num_devices(mesh: Mesh) -> int:

@@ -1,18 +1,29 @@
 """Serving driver: batched requests through the continuous-batching engine.
 
-CPU example (reduced config, AnchorAttention prefill):
+Weights are random from ``--seed``; no checkpoint is read.  Prefill runs
+AnchorAttention with the paper's blocking (128/128/16, θ=12) and a
+``--capacity`` stripe budget, on the backend ``repro.kernels.dispatch``
+picks for the device (``pallas_tpu`` on a TPU) unless ``--backend`` is
+given.
+
+Full width on one TPU (internlm2-1.8b):
+    PYTHONPATH=src python -m repro.launch.serve --arch internlm2_1p8b \
+        --requests 4 --prompt-len 8192 --max-new 16 --paged
+
+CPU example (reduced config; prompts pad to two 2048-token superblocks):
     PYTHONPATH=src python -m repro.launch.serve --arch yi_9b --reduced \
-        --requests 6 --prompt-len 64 --max-new 8
+        --backend xla --requests 6 --prompt-len 64 --max-new 8
 
 Paged KV-cache serving (shared pool + prefix sharing + chunked prefill):
     PYTHONPATH=src python -m repro.launch.serve --arch yi_9b --reduced \
-        --requests 6 --prompt-len 64 --max-new 8 \
-        --paged --page-size 16 --shared-prefix 32 --chunk-tokens 64
+        --backend xla --requests 6 --prompt-len 4000 --max-new 8 \
+        --paged --page-size 16 --shared-prefix 2048 --chunk-tokens 2048
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -20,11 +31,19 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced_config
-from repro.core.config import AnchorConfig
+from repro.core.config import PAPER_CONFIG
 from repro.core.spec import AttentionSpec
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving import Request, ServingEngine
+
+
+def device_info() -> dict:
+    """The device JAX runs on, as every result line names it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
 
 
 def main() -> None:
@@ -35,7 +54,10 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-batch", type=int, default=4)
-    ap.add_argument("--theta", type=float, default=12.0)
+    ap.add_argument("--theta", type=float, default=PAPER_CONFIG.theta)
+    ap.add_argument("--capacity", type=int, default=4096,
+                    help="stripes kept per superblock and query head "
+                         "(0: all candidates)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--backend", default=None, choices=dispatch.BACKENDS,
                     help="kernel backend (default: platform-appropriate)")
@@ -56,25 +78,22 @@ def main() -> None:
                     help="print an engine.stats snapshot every N engine "
                          "steps (0: only the final snapshot)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.backend:
         dispatch.set_default_backend(args.backend)
+    backend = dispatch.default_backend()
+    print(f"device: {json.dumps(device_info())} backend: {backend}")
 
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.embed_input:
         raise SystemExit(f"{args.arch} is an embed-input stub arch; "
                          "use a token arch for the serving demo")
     params = model_lib.init(jax.random.PRNGKey(args.seed), cfg)
-    anchor_cfg = AnchorConfig(
-        block_q=16, block_kv=16, step=2, theta=args.theta,
-        backend=args.backend)
-    # An explicit pallas --backend routes prefill through the dispatched
-    # kernel pipeline; "xla" (and the default) pin the same pipeline to
-    # the XLA backend, which also carries the f32-input guard against
-    # bf16 MoE routing flips (repro.kernels.ops.attention).
-    spec = AttentionSpec(
-        algorithm="anchor",
-        backend=args.backend if args.backend else "xla",
-        anchor=anchor_cfg)
+    anchor_cfg = dataclasses.replace(
+        PAPER_CONFIG, theta=args.theta, capacity=args.capacity or None,
+        backend=backend)
+    spec = AttentionSpec(algorithm="anchor", backend=backend,
+                         anchor=anchor_cfg)
     # Cache must fit prompts padded for sparse prefill or the engine
     # records a dense fallback.
     max_len = anchor_cfg.prefill_pad_len(args.prompt_len) + args.max_new + 8
@@ -121,7 +140,7 @@ def main() -> None:
               f"{req.generated[:8]}")
     total_tokens = sum(len(r.generated) for r in done)
     print(f"{len(done)} requests, {total_tokens} tokens in {dt:.1f}s "
-          f"({total_tokens / max(dt, 1e-9):.1f} tok/s CPU)")
+          f"on {device_info()['kind']} (wall time incl. compilation)")
     print(f"engine stats: {json.dumps(engine.snapshot())}")
 
 

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, mlp_apply
 

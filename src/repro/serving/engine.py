@@ -304,8 +304,10 @@ class ServingEngine:
         Pages may only be shared between requests whose prefill computes
         the prefix KV with the *same attention math* — mixing would let a
         request decode against KV it would not itself have produced.
-        Anchor is bitwise invariant to the padded wave length on xla
-        (tested), so one tag per algorithm suffices:
+        Anchor prefill at two padded wave lengths agrees to a few f32 ulp
+        (``atol = rtol = 1e-6`` on xla, tested; the dot kernel, not the
+        selection, is what differs — DESIGN.md §3), so one tag per
+        algorithm suffices:
 
         * chunked prompts — ``"chunked"`` (checked FIRST: with chunking
           on, a long prompt always chunks — under an anchor spec the

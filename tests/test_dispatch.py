@@ -1,19 +1,17 @@
-"""Backend-dispatch layer + compat shim tests.
+"""Backend-dispatch layer tests.
 
 Parity: the ``xla`` and ``pallas_interpret`` backends of every public op
 must agree with the dense oracles in ``kernels/ref.py`` (and with each
-other).  Compat: the symbol-resolution helpers must handle both the old
-(0.4.x) and new (0.5+/0.7+) JAX layouts, exercised here against fakes.
+other).  The JAX APIs the kernels and collectives call directly
+(``pltpu.CompilerParams``, ``AbstractMesh``, ``jax.shard_map``) are
+checked against the installed JAX.
 """
-
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core import AnchorConfig
 from repro.kernels import dispatch
 from repro.kernels import ops as kernel_ops
@@ -215,107 +213,27 @@ class TestDispatchRegistry:
 
 
 class TestCompatShims:
-    """Symbol resolution against fakes of both the old and new JAX layouts."""
-
-    def test_shard_map_new_home(self):
-        sentinel = object()
-        fake_jax = types.SimpleNamespace(shard_map=sentinel)
-        assert compat._resolve_shard_map(fake_jax) is sentinel
-
-    def test_shard_map_experimental_fallback(self):
-        sentinel = object()
-        fake_jax = types.SimpleNamespace()  # no jax.shard_map (0.4.x)
-        fake_exp = types.SimpleNamespace(shard_map=sentinel)
-        assert compat._resolve_shard_map(fake_jax, fake_exp) is sentinel
-
-    def test_shard_map_neither_raises(self):
-        with pytest.raises(ImportError):
-            compat._resolve_shard_map(
-                types.SimpleNamespace(), types.SimpleNamespace())
-
-    def test_check_vma_translates_to_check_rep(self):
-        captured = {}
-
-        def old_shard_map(f, *, mesh, in_specs, out_specs, check_rep=True):
-            captured.update(check_rep=check_rep)
-            return f
-
-        wrapped = compat._make_shard_map(old_shard_map)
-        wrapped(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                check_vma=False)
-        assert captured == {"check_rep": False}
-
-    def test_check_vma_passes_through_on_new_jax(self):
-        captured = {}
-
-        def new_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-            captured.update(check_vma=check_vma)
-            return f
-
-        wrapped = compat._make_shard_map(new_shard_map)
-        wrapped(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                check_vma=False)
-        assert captured == {"check_vma": False}
-
-    def test_check_vma_dropped_when_knob_gone(self):
-        def bare_shard_map(f, *, mesh, in_specs, out_specs):
-            return f
-
-        wrapped = compat._make_shard_map(bare_shard_map)
-        assert wrapped(lambda x: x, mesh=None, in_specs=(), out_specs=(),
-                       check_vma=False)(1) == 1
-
-    def test_tpu_compiler_params_old_name(self):
-        class FakeParams:
-            def __init__(self, **kw):
-                self.kw = kw
-
-        mod = types.SimpleNamespace(TPUCompilerParams=FakeParams)
-        cls = compat._resolve_tpu_compiler_params(mod)
-        assert cls is FakeParams
-
-    def test_tpu_compiler_params_new_name_wins(self):
-        old, new = type("Old", (), {}), type("New", (), {})
-        mod = types.SimpleNamespace(TPUCompilerParams=old, CompilerParams=new)
-        assert compat._resolve_tpu_compiler_params(mod) is new
-
-    def test_tpu_compiler_params_neither_raises(self):
-        with pytest.raises(AttributeError):
-            compat._resolve_tpu_compiler_params(types.SimpleNamespace())
+    """The installed JAX's own spellings, as the call sites use them."""
 
     def test_tpu_compiler_params_real_jax(self):
-        params = compat.tpu_compiler_params(
+        from jax.experimental.pallas import tpu as pltpu
+
+        params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
         assert params.dimension_semantics == ("parallel", "arbitrary")
 
-    def test_abstract_mesh_old_layout(self):
-        class OldMesh:
-            def __init__(self, shape_tuple):
-                self.shape_tuple = shape_tuple
-
-        mod = types.SimpleNamespace(AbstractMesh=OldMesh)
-        m = compat.abstract_mesh((4, 2), ("data", "model"), mod)
-        assert m.shape_tuple == (("data", 4), ("model", 2))
-
-    def test_abstract_mesh_new_layout(self):
-        class NewMesh:
-            def __init__(self, axis_sizes, axis_names):
-                self.axis_sizes, self.axis_names = axis_sizes, axis_names
-
-        mod = types.SimpleNamespace(AbstractMesh=NewMesh)
-        m = compat.abstract_mesh((4, 2), ("data", "model"), mod)
-        assert m.axis_sizes == (4, 2) and m.axis_names == ("data", "model")
-
     def test_abstract_mesh_real_jax(self):
-        m = compat.abstract_mesh((8, 2), ("data", "model"))
+        m = jax.sharding.AbstractMesh((8, 2), ("data", "model"))
         assert tuple(m.axis_names) == ("data", "model")
 
     def test_real_shard_map_runs(self):
-        """The wrapped shard_map executes on the real single-device mesh."""
+        """jax.shard_map executes on the real single-device mesh."""
         from jax.sharding import PartitionSpec as P
 
-        mesh = jax.make_mesh((1,), ("data",))
-        out = compat.shard_map(
+        from repro.launch.mesh import make_debug_mesh
+
+        mesh = make_debug_mesh((1,), ("data",))
+        out = jax.shard_map(
             lambda x: x * 2, mesh=mesh, in_specs=(P(),), out_specs=P(),
             check_vma=False)(jnp.arange(4.0))
         np.testing.assert_allclose(np.asarray(out), [0.0, 2.0, 4.0, 6.0])

@@ -5,15 +5,18 @@ Multi-device cases run in a subprocess with
 keeps seeing exactly one device (assignment requirement).
 """
 
+import os
 import subprocess
 import sys
 import textwrap
 
 import jax
 import numpy as np
+from jax.sharding import AbstractMesh
 
-from repro.compat import abstract_mesh
 from repro.distributed import sharding as sh
+
+_REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
 def _run_subprocess(body: str) -> str:
@@ -24,10 +27,11 @@ def _run_subprocess(body: str) -> str:
         sys.path.insert(0, "src")
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_debug_mesh
     """) + textwrap.dedent(body)
     res = subprocess.run(
         [sys.executable, "-c", prog], capture_output=True, text=True,
-        timeout=600, cwd="/root/repo")
+        timeout=600, cwd=_REPO)
     assert res.returncode == 0, res.stderr[-3000:]
     return res.stdout
 
@@ -35,14 +39,14 @@ def _run_subprocess(body: str) -> str:
 class TestShardingRules:
     def test_divisibility_fallback(self):
         """Odd vocab (50280) on a 16-way axis must replicate, not crash."""
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         spec = sh.param_pspec(("embed",), (50280, 2560), mesh)
         assert spec[0] is None  # vocab replicated (50280 % 16 != 0)
         divisible = sh.param_pspec(("embed",), (50288, 2560), mesh)
         assert divisible[0] == "model"
 
     def test_attention_rules(self):
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         P = jax.sharding.PartitionSpec
         # wq: shard output (heads) dim
         assert sh.param_pspec(("blocks", "l0", "attn", "wq"), (16, 2048, 2048), mesh)[-1] == "model"
@@ -57,7 +61,7 @@ class TestShardingRules:
         out = _run_subprocess("""
             from repro.distributed.collectives import flash_decode_sharded
             from repro.models.layers import decode_attention
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_debug_mesh((4, 2), ("data", "model"))
             B, H, S, D = 2, 4, 64, 16
             ks = jax.random.split(jax.random.PRNGKey(0), 3)
             q = jax.random.normal(ks[0], (B, H, 1, D))
@@ -81,7 +85,7 @@ class TestShardingRules:
         out = _run_subprocess("""
             from repro.distributed.collectives import flash_decode_sharded
             from repro.models.layers import decode_attention
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_debug_mesh((2, 4), ("data", "model"))
             B, HQ, HKV, S, D = 2, 8, 2, 64, 16
             ks = jax.random.split(jax.random.PRNGKey(0), 3)
             q = jax.random.normal(ks[0], (B, HQ, 1, D))
@@ -104,7 +108,7 @@ class TestShardingRules:
             from repro.models import moe as moe_lib
             from repro.models.moe import MoEParallelism
             cfg = get_reduced_config("granite_moe_1b_a400m")  # 8 experts
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_debug_mesh((2, 4), ("data", "model"))
             p = moe_lib.moe_init(jax.random.PRNGKey(0), cfg)
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
             ref, aux_ref = moe_lib.moe_apply(x, p, cfg, capacity_factor=100.0)
@@ -122,9 +126,9 @@ class TestShardingRules:
     def test_compressed_psum_mean(self):
         out = _run_subprocess("""
             from jax.sharding import PartitionSpec as P
-            from repro.compat import shard_map
+            from jax import shard_map
             from repro.optim.compression import compressed_psum
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_debug_mesh((8,), ("data",))
             g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
             res = jnp.zeros((8, 64))
             def body(g, r):
@@ -156,7 +160,7 @@ class TestShardingRules:
             toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
             batch = {"tokens": toks, "labels": toks}
             loss_single, _ = model_lib.loss_fn(params, batch, cfg)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_debug_mesh((4, 2), ("data", "model"))
             pshard = sh.param_shardings(params, mesh)
             with mesh:
                 pp = jax.device_put(params, pshard)
@@ -238,12 +242,12 @@ class TestElasticRescale:
             from repro.models import model as model_lib
             cfg = get_reduced_config("yi_9b")
             params = model_lib.init(jax.random.PRNGKey(0), cfg)
-            mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+            mesh_a = make_debug_mesh((4, 2), ("data", "model"))
             params_a = jax.device_put(params, sh.param_shardings(params, mesh_a))
             d = tempfile.mkdtemp()
             mgr = CheckpointManager(d)
             mgr.save(1, params_a)
-            mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+            mesh_b = make_debug_mesh((2, 4), ("data", "model"))
             shard_b = sh.param_shardings(params, mesh_b)
             step, params_b = mgr.restore(params, sharding_tree=shard_b)
             for a, b in zip(jax.tree.leaves(params_a), jax.tree.leaves(params_b)):

@@ -206,14 +206,10 @@ class TestAnchorSlots:
 
 
 def _walk_eqns(jaxpr, fn):
-    from jax.core import Jaxpr
-    try:
-        from jax.core import ClosedJaxpr
-    except ImportError:  # pragma: no cover
-        ClosedJaxpr = None
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def sub_jaxprs(val):
-        if ClosedJaxpr is not None and isinstance(val, ClosedJaxpr):
+        if isinstance(val, ClosedJaxpr):
             yield val.jaxpr
         elif isinstance(val, Jaxpr):
             yield val
@@ -376,25 +372,22 @@ class TestCompactMetrics:
 
 
 class TestLengthGridOperand:
+    """The shared varlen operand every Pallas kernel scalar-prefetches."""
+
     @pytest.mark.quick
     def test_values_and_spec(self):
         lengths = jnp.asarray([3, 7], jnp.int32)
-        operand, spec = indexing.length_grid_operand(lengths, 2, 4, 32)
-        assert operand.shape == (8, 1)
-        np.testing.assert_array_equal(
-            np.asarray(operand[:, 0]), [3, 3, 3, 3, 7, 7, 7, 7])
-        # The (1, 1) BlockSpec picks row b whatever the grid arity is.
-        assert spec.block_shape == (1, 1)
-        assert spec.index_map(5) == (5, 0)
-        assert spec.index_map(5, 1, 2) == (5, 0)
-        assert spec.index_map(5, 1, 2, None, None) == (5, 0)
+        operand = indexing.prefetch_lengths(lengths, 2, 32)
+        # One int32 per batch row, 1-D: kernels index it from SMEM with
+        # their grid row's batch id, whatever the grid arity is.
+        assert operand.shape == (2,)
+        np.testing.assert_array_equal(np.asarray(operand), [3, 7])
 
     def test_none_means_fully_valid(self):
-        operand, _ = indexing.length_grid_operand(None, 3, 2, 17)
-        assert operand.shape == (6, 1)
+        operand = indexing.prefetch_lengths(None, 3, 17)
+        assert operand.shape == (3,)
         assert (np.asarray(operand) == 17).all()
 
     def test_dtype_coerced(self):
-        operand, _ = indexing.length_grid_operand(
-            jnp.asarray([4.0, 5.0]), 2, 1, 8)
+        operand = indexing.prefetch_lengths(jnp.asarray([4.0, 5.0]), 2, 8)
         assert operand.dtype == jnp.int32

@@ -42,14 +42,17 @@ lengths_strategy = st.lists(
 @given(seed=st.integers(0, 50), lens=lengths_strategy,
        algorithm=st.sampled_from(["dense", "anchor"]))
 def test_padded_batch_equals_per_sequence_ops(seed, lens, algorithm):
-    """kernels.ops.attention: batched padded call == per-sequence calls,
-    bit-for-bit on xla, within tolerance on pallas_interpret; padded rows
-    are exact zeros."""
+    """kernels.ops.attention: batched padded call == per-sequence calls
+    to a few f32 ulp on xla (XLA:CPU picks its dot kernel by operand
+    shape, and the batch size is part of the shape — DESIGN.md §3),
+    within kernel tolerance on pallas_interpret; padded rows are exact
+    zeros."""
     b = len(lens)
     q, k, v = _qkv(seed, b, 2, N_PAD, 16)
     lengths = jnp.asarray(lens, jnp.int32)
 
-    for backend, exact in (("xla", True), ("pallas_interpret", False)):
+    for backend, (atol, rtol) in (("xla", (1e-6, 1e-6)),
+                                  ("pallas_interpret", (2e-5, 1e-4))):
         spec = AttentionSpec(algorithm=algorithm, backend=backend,
                              anchor=ANCHOR, masking="padded")
         out = kernel_ops.attention(q, k, v, spec, lengths=lengths)
@@ -59,14 +62,9 @@ def test_padded_batch_equals_per_sequence_ops(seed, lens, algorithm):
             single = kernel_ops.attention(
                 q[j:j + 1], k[j:j + 1], v[j:j + 1], spec,
                 lengths=jnp.asarray([n], jnp.int32))
-            if exact:
-                np.testing.assert_array_equal(
-                    np.asarray(out[j]), np.asarray(single[0]))
-            else:
-                np.testing.assert_allclose(
-                    np.asarray(out[j], np.float32),
-                    np.asarray(single[0], np.float32),
-                    atol=2e-5, rtol=1e-4)
+            np.testing.assert_allclose(
+                np.asarray(out[j], np.float32),
+                np.asarray(single[0], np.float32), atol=atol, rtol=rtol)
 
 
 @pytest.fixture(scope="module")
