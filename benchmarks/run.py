@@ -27,7 +27,6 @@ SUITES = [
     "prefill_index",  # gather-based vs index-driven sparse stage
     "ruler_proxy",  # Table 3 proxy
     "roofline_report",  # §Dry-run / §Roofline
-    "serving_throughput",  # dense-slab vs paged KV-cache engine
 ]
 
 

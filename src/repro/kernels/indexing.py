@@ -215,6 +215,35 @@ def merge_anchor_slots(
     )
 
 
+SPARSE_COUNTERS = ("stripes_kept", "stripe_tiles_kept", "sparse_slots_used",
+                   "sparse_slots_visited")
+
+
+def sparse_counters(counts: jnp.ndarray, sel: StripeIndex,
+                    tables: StripeIndex, t_m: int, step: int) -> jnp.ndarray:
+    """(4,) int32 work counts of one sparse stage, in ``SPARSE_COUNTERS``
+    order, from the very tables its sweep consumes:
+
+    * ``stripes_kept`` — Σ of the selection's per-head kept ``counts``;
+    * ``stripe_tiles_kept`` — occupied selection slots of ``sel`` (the
+      anchor slots excluded);
+    * ``sparse_slots_used`` — occupied slots of the merged ``tables``
+      (anchor slots included), once per query block that walks them;
+    * ``sparse_slots_visited`` — the slots the sweep's grid
+      ``(B·Hkv, T_m, C_t)`` walks, occupied or not.
+
+    ``t_m`` is the number of ``block_q`` query blocks; superblock ``s``
+    holds ``min(step, t_m - s·step)`` of them.
+    """
+    b, hkv, t_s, c_t = tables.tile_idx.shape
+    blocks = jnp.minimum(step, t_m - step * jnp.arange(t_s))  # (T_s,)
+    used = jnp.sum(tables.tile_valid.sum(axis=-1) * blocks)
+    return jnp.stack([
+        jnp.sum(counts), jnp.sum(sel.tile_valid), used,
+        jnp.asarray(b * hkv * t_m * c_t),
+    ]).astype(jnp.int32)
+
+
 def pack_stripe_indices(
     hit: jnp.ndarray, capacity: int
 ) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -95,7 +95,8 @@ def attention(
     spec: AttentionSpec | None = None,
     *,
     lengths: jnp.ndarray | None = None,
-) -> jnp.ndarray:
+    return_counters: bool = False,
+):
     """Canonical attention entry point — ``repro.attention``.
 
     Args:
@@ -106,9 +107,11 @@ def attention(
         (and only allowed) when ``spec.masking == "padded"``.  Padding
         keys are masked out of scores, statistics, and stripe selection;
         padded query rows return exact zeros.
+      return_counters: also return the sparse stage's (4,) int32 work
+        counts (``indexing.SPARSE_COUNTERS``; zeros for dense).
 
     Returns:
-      (B, Hq, N, Dv) attention output in ``q.dtype``.
+      (B, Hq, N, Dv) attention output in ``q.dtype`` (and the counts).
     """
     spec = spec if spec is not None else AttentionSpec()
     if spec.masking == "padded" and lengths is None:
@@ -130,11 +133,17 @@ def attention(
         # traffic, which is the point.
         q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
     if spec.algorithm == "dense":
-        out = flash_attention(q, k, v, lengths=lengths, backend=backend)
+        res = flash_attention(q, k, v, lengths=lengths, backend=backend)
+        if return_counters:
+            res = res, jnp.zeros((len(indexing.SPARSE_COUNTERS),), jnp.int32)
     else:
-        out = anchor_attention(q, k, v, spec.anchor, lengths=lengths,
-                               backend=backend)
-    return out.astype(out_dtype)
+        res = anchor_attention(q, k, v, spec.anchor, lengths=lengths,
+                               backend=backend,
+                               return_counters=return_counters)
+    if return_counters:
+        out, counters = res
+        return out.astype(out_dtype), counters
+    return res.astype(out_dtype)
 
 
 def flash_attention(
@@ -290,22 +299,27 @@ def anchor_attention(
     return_stats: bool = False,
     lengths: jnp.ndarray | None = None,
     backend: str | None = None,
+    return_counters: bool = False,
 ):
     """Full AnchorAttention.  q: (B, Hq, N, D); k, v: (B, Hkv, N, D).
 
     ``lengths`` ((B,) int32, optional) masks a right-padded batch:
     padding keys never enter statistics or selection, padded rows return
-    zeros.
+    zeros.  ``return_counters`` pairs the result with the sparse stage's
+    (4,) int32 work counts (:func:`repro.kernels.indexing.sparse_counters`).
     """
     fn, _ = dispatch.lookup("anchor_attention", backend)
     kw = {} if block_c is None else {"block_c": block_c}
     if lengths is not None:
         kw["lengths"] = lengths
+    if return_counters:
+        kw["return_counters"] = True
     return fn(q, k, v, cfg, return_stats=return_stats, **kw)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "block_c", "return_stats", "backend")
+    jax.jit, static_argnames=("cfg", "block_c", "return_stats", "backend",
+                              "return_counters")
 )
 def _anchor_attention_pipeline(
     q: jnp.ndarray,
@@ -317,6 +331,7 @@ def _anchor_attention_pipeline(
     lengths: jnp.ndarray | None = None,
     *,
     backend: str,
+    return_counters: bool = False,
 ):
     """Fused AnchorAttention: scores → compact select → one sparse sweep.
 
@@ -357,9 +372,11 @@ def _anchor_attention_pipeline(
         # Padded query rows produce exact zeros.
         rows = jnp.arange(n)[None, None, :, None] < lengths[:, None, None, None]
         out = jnp.where(rows, out, jnp.zeros((), out.dtype))
-    if return_stats:
-        return out, counts
-    return out
+    res = (out, counts) if return_stats else out
+    if return_counters:
+        return res, indexing.sparse_counters(
+            counts, sel, tables, cfg.num_q_blocks(n), cfg.step)
+    return res
 
 
 for _backend in dispatch.BACKENDS:
@@ -437,7 +454,8 @@ def anchor_attention_staged(
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "block_c", "backend"))
+@functools.partial(jax.jit, static_argnames=("cfg", "block_c", "backend",
+                                             "return_counters"))
 def _chunk_anchor_impl(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,
@@ -448,6 +466,7 @@ def _chunk_anchor_impl(
     live: jnp.ndarray | None = None,
     *,
     backend: str,
+    return_counters: bool = False,
 ):
     """Fused AnchorAttention for one superblock-aligned chunk over a KV
     cache.
@@ -541,12 +560,15 @@ def _chunk_anchor_impl(
     # --- Compact selection over the history + one fused sparse sweep.
     tile = indexing.stripe_tile(s_len, min(block_c, s_len))
     sb0 = pos // sb
-    sel, _ = stripe_select_xla(
+    sel, counts = stripe_select_xla(
         q_mean.reshape(b, hq, t_mc, d), m_bar.reshape(b, hq, t_mc),
         k_cache, cfg, tile, sb0=sb0)
     tables = merge_anchor_slots(sel, s_len, cfg, sb0=sb0)
     sparse_fn, _ = dispatch.lookup("sparse_attention", backend)
     out = sparse_fn(q, k_cache, v_cache, tables, cfg, q_offset=pos)
+    if return_counters:
+        return out.astype(q.dtype), indexing.sparse_counters(
+            counts, sel, tables, t_mc, cfg.step)
     return out.astype(q.dtype)
 
 
@@ -559,7 +581,8 @@ def chunk_anchor_attention(
     block_c: int | None = None,
     live: jnp.ndarray | None = None,
     backend: str | None = None,
-) -> jnp.ndarray:
+    return_counters: bool = False,
+):
     """Fused AnchorAttention for one chunk of a chunked prefill.
 
     q: (B, Hq, C, D) chunk queries (``C % cfg.superblock_q() == 0``);
@@ -568,9 +591,11 @@ def chunk_anchor_attention(
     start; live: () int32 real rows of a zero-padded final chunk (rows
     >= live are excluded from the pooled identification statistics and
     their outputs are garbage the caller discards).  Returns
-    (B, Hq, C, Dv).
+    (B, Hq, C, Dv), paired with the chunk's (4,) int32 sparse work counts
+    under ``return_counters``.
     """
     backend = dispatch.resolve_backend(backend)
     kw = {} if block_c is None else {"block_c": block_c}
     return _chunk_anchor_impl(
-        q, k_cache, v_cache, pos, cfg, live=live, backend=backend, **kw)
+        q, k_cache, v_cache, pos, cfg, live=live, backend=backend,
+        return_counters=return_counters, **kw)

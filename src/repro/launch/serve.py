@@ -4,7 +4,9 @@ Weights are random from ``--seed``; no checkpoint is read.  Prefill runs
 AnchorAttention with the paper's blocking (128/128/16, θ=12) and a
 ``--capacity`` stripe budget, on the backend ``repro.kernels.dispatch``
 picks for the device (``pallas_tpu`` on a TPU) unless ``--backend`` is
-given.
+given.  With its final stats it prints the engine's spans
+(``repro.serving.tracing``): per span name the count, total and longest
+seconds.
 
 Full width on one TPU (internlm2-1.8b):
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2_1p8b \
@@ -36,7 +38,7 @@ from repro.core.spec import AttentionSpec
 from repro.kernels import dispatch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
-from repro.serving import Request, ServingEngine
+from repro.serving import Request, ServingEngine, tracing
 
 
 def device_info() -> dict:
@@ -122,6 +124,7 @@ def main() -> None:
     shared = rng.integers(0, cfg.vocab_size,
                           size=args.shared_prefix).astype(np.int32)
     t0 = time.time()
+    t0_spans = time.perf_counter()
     for uid in range(args.requests):
         own = max(1, args.prompt_len - args.shared_prefix)
         prompt = np.concatenate(
@@ -142,6 +145,10 @@ def main() -> None:
     print(f"{len(done)} requests, {total_tokens} tokens in {dt:.1f}s "
           f"on {device_info()['kind']} (wall time incl. compilation)")
     print(f"engine stats: {json.dumps(engine.snapshot())}")
+    print("engine spans (name, count, total s, max s):")
+    for name, (n, total, top) in tracing.totals(
+            engine.spans(t0_spans)).items():
+        print(f"  {name:24s} {n:6d} {total:10.4f} {top:9.4f}")
 
 
 if __name__ == "__main__":

@@ -361,7 +361,7 @@ def build_group_probe(
                 moe_parallel=moe_par, sp_spec=sp_spec)
 
             def f(gp_):
-                y, (aux, _) = group_fn(x, gp_)
+                y, (aux, _, _) = group_fn(x, gp_)
                 return jnp.sum(y.astype(jnp.float32)) + aux
 
             return jax.grad(f)(gp)
@@ -375,7 +375,7 @@ def build_group_probe(
                 cfg, jnp.broadcast_to(jnp.arange(n), (x.shape[0], n)),
                 spec=attn_spec,
                 remat=False, return_cache=True, moe_parallel=moe_par)
-            y, (aux, caches) = group_fn(x, gp)
+            y, (aux, _, caches) = group_fn(x, gp)
             return y, caches
 
         fn = jax.jit(probe)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.spec import AttentionSpec
+from repro.kernels.indexing import SPARSE_COUNTERS
 from repro.models.cache import NULL_PAGE, PagedKVLayout
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -38,13 +39,15 @@ Params = dict[str, Any]
 
 
 def _prefill_attention(q, k, v, spec: AttentionSpec | None,
-                       lengths: jnp.ndarray | None = None):
+                       lengths: jnp.ndarray | None = None,
+                       return_counters: bool = False):
     from repro.kernels import ops as kernel_ops
 
     spec = spec if spec is not None else AttentionSpec(backend="xla")
     if lengths is not None and spec.masking != "padded":
         spec = spec.padded()
-    return kernel_ops.attention(q, k, v, spec, lengths=lengths)
+    return kernel_ops.attention(q, k, v, spec, lengths=lengths,
+                                return_counters=return_counters)
 
 
 # ------------------------------------------------------------------ GQA ----
@@ -76,7 +79,12 @@ def gqa_apply(
     lengths: jnp.ndarray | None = None,
     return_cache: bool = False,
 ):
-    """Prefill self-attention.  x: (B, N, d_model); positions: (B, N)."""
+    """Prefill self-attention.  x: (B, N, d_model); positions: (B, N).
+
+    ``return_cache`` (the serving prefill) returns ``(out, cache,
+    counters)``: the layer's K/V and the sparse stage's (4,) int32 work
+    counts (``repro.kernels.indexing.SPARSE_COUNTERS``; zeros for dense).
+    """
     b, n, _ = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = (x @ p["wq"]).reshape(b, n, h, hd)
@@ -88,11 +96,15 @@ def gqa_apply(
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))  # (B, H, N, D)
-    out = _prefill_attention(q, k, v, spec, lengths)
+    out = _prefill_attention(q, k, v, spec, lengths,
+                             return_counters=return_cache)
+    if return_cache:
+        out, counters = out
     out = jnp.swapaxes(out, 1, 2).reshape(b, n, h * hd)
     out = out @ p["wo"]
     if return_cache:
-        return out, {"k": k, "v": v}  # rope'd K — matches gqa_decode layout
+        # rope'd K — matches gqa_decode layout
+        return out, {"k": k, "v": v}, counters
     return out
 
 
@@ -211,6 +223,10 @@ def gqa_chunk_apply(
     chunked long prompts keep AnchorAttention prefill instead of falling
     back to dense history attention.  Otherwise: dense
     :func:`repro.models.layers.chunk_attention`.
+
+    Returns ``(out, cache, counters)``: the sparse chunk's (4,) int32
+    work counts (``repro.kernels.indexing.SPARSE_COUNTERS``), zeros on
+    the dense path.
     """
     b, c, _ = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -233,13 +249,14 @@ def gqa_chunk_apply(
     if sparse:
         from repro.kernels import ops as kernel_ops
 
-        out = kernel_ops.chunk_anchor_attention(
+        out, counters = kernel_ops.chunk_anchor_attention(
             q, k_cache, v_cache, pos, spec.anchor, live=live,
-            backend=spec.backend)
+            backend=spec.backend, return_counters=True)
     else:
         out = chunk_attention(q, k_cache, v_cache, pos)
+        counters = jnp.zeros((len(SPARSE_COUNTERS),), jnp.int32)
     out = jnp.swapaxes(out, 1, 2).reshape(b, c, h * hd)
-    return out @ p["wo"], {"k": k_cache, "v": v_cache}
+    return out @ p["wo"], {"k": k_cache, "v": v_cache}, counters
 
 
 # ------------------------------------------------------------------ MLA ----
@@ -299,11 +316,14 @@ def mla_apply(
     q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
     # Note the asymmetric head dims (qk: nope+rope, v: v_head_dim); the
     # anchor/pallas paths support that directly (D only enters via scale).
-    out = _prefill_attention(q, k, v, spec, lengths)
+    out = _prefill_attention(q, k, v, spec, lengths,
+                             return_counters=return_cache)
+    if return_cache:
+        out, counters = out
     out = jnp.swapaxes(out, 1, 2).reshape(b, n, cfg.num_heads * cfg.v_head_dim)
     out = out @ p["wo"]
     if return_cache:
-        return out, cache
+        return out, cache, counters  # as gqa_apply
     return out
 
 

@@ -139,7 +139,8 @@ def prefill(
     anchor_cfg: AnchorConfig | None = None,
     ssm_impl: str = "xla",
     moe_parallel: MoEParallelism | None = None,
-) -> tuple[jnp.ndarray, Params]:
+    return_counters: bool = False,
+):
     """Serving prefill: last-position logits + populated per-layer cache.
 
     This is the step the paper accelerates — the default spec runs
@@ -151,6 +152,11 @@ def prefill(
     returned logits are taken at each sequence's own last valid position,
     and cache positions beyond a sequence's length hold padding (callers
     resume decode at ``pos = lengths[b]``).
+
+    ``return_counters`` pairs the cache with (num_groups, 4) int32, each
+    layer group's sparse-attention work in
+    ``repro.kernels.indexing.SPARSE_COUNTERS`` order (zeros for dense):
+    the result is then ``(logits, (cache, counters))``.
     """
     spec = resolve_attention_spec(spec, attn_impl, anchor_cfg,
                                   default_algorithm="anchor")
@@ -167,7 +173,7 @@ def prefill(
         x = jnp.take(params["embed"], tokens, axis=0)
         b, n = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(n), (b, n))
-    x, _, cache = transformer.stack_apply(
+    x, _, cache, counters = transformer.stack_apply(
         x, params["blocks"], cfg, positions,
         spec=spec, lengths=lengths, ssm_impl=ssm_impl,
         remat=False, return_cache=True, moe_parallel=moe_parallel)
@@ -179,6 +185,8 @@ def prefill(
         x_last = jnp.take_along_axis(
             x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1)
     x = rmsnorm(x_last, params["final_norm"], cfg.norm_eps)
+    if return_counters:
+        return _logits(x, params)[:, 0], (cache, counters)
     return _logits(x, params)[:, 0], cache
 
 
@@ -226,23 +234,24 @@ def prefill_chunk(
     pos: jnp.ndarray,
     spec: AttentionSpec | None = None,
     live: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, Params]:
+) -> tuple[jnp.ndarray, Params, jnp.ndarray]:
     """One chunk of a chunked prefill.  tokens: (B, C) int32; ``cache``
     holds dense per-sequence views already containing ``[0, pos)``.
 
     Returns (logits (B, C, V) — the caller reads the row of its last
-    valid chunk token — and the updated cache views with the chunk's K/V
-    written at ``[pos, pos + C)``).  GQA-attention-only; see
+    valid chunk token — the updated cache views with the chunk's K/V
+    written at ``[pos, pos + C)``, and the chunk's (num_groups, 4) int32
+    sparse-attention work as :func:`prefill`'s).  GQA-attention-only; see
     :func:`transformer.stack_chunk_prefill`.  An ``anchor`` ``spec``
     keeps the chunk on the index-driven sparse path (superblock-aligned
     chunks); ``None``/dense runs dense history attention.  ``live``
     (() int32) is the real-token count of a zero-padded final chunk.
     """
     x = jnp.take(params["embed"], tokens, axis=0)
-    x, new_cache = transformer.stack_chunk_prefill(
+    x, new_cache, counters = transformer.stack_chunk_prefill(
         x, params["blocks"], cache, cfg, pos, spec=spec, live=live)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return _logits(x, params), new_cache
+    return _logits(x, params), new_cache, counters
 
 
 def init_cache(

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.core.spec import AttentionSpec
+from repro.kernels.indexing import SPARSE_COUNTERS
 from repro.models import attention as attn_lib
 from repro.models import cache as cache_lib
 from repro.models import moe as moe_lib
@@ -84,16 +85,19 @@ def _layer_apply(
 ):
     aux = jnp.zeros((), jnp.float32)
     cache = None
+    counters = jnp.zeros((len(SPARSE_COUNTERS),), jnp.int32)
     h = rmsnorm(x, p["norm_mixer"], cfg.norm_eps)
     if mixer == "attn":
         apply = attn_lib.mla_apply if cfg.use_mla else attn_lib.gqa_apply
         h = apply(h, p["attn"], cfg, positions, spec=spec,
                   lengths=lengths, return_cache=return_cache)
+        if return_cache:
+            h, cache, counters = h
     else:
         h = ssm_lib.mamba_apply(h, p["mamba"], cfg, ssm_impl=ssm_impl,
                                 return_cache=return_cache)
-    if return_cache:
-        h, cache = h
+        if return_cache:
+            h, cache = h
     if sp_spec is not None:
         # Megatron-SP: the row-parallel output reduce-scatters onto the
         # sequence dim (over `model`); the saved activation is 1/TP-sized.
@@ -110,7 +114,7 @@ def _layer_apply(
             h = jax.lax.with_sharding_constraint(h, sp_spec)
         h = checkpoint_name(h, "tp_ffn_out")
         x = x + h
-    return x, aux, cache
+    return x, aux, cache, counters
 
 
 def make_group_fn(
@@ -126,7 +130,10 @@ def make_group_fn(
     moe_parallel: MoEParallelism | None = None,
     sp_spec=None,
 ):
-    """One scan-group body ``(x, group_params) -> (x, (aux, caches))``.
+    """One scan-group body ``(x, group_params) -> (x, (aux, counters,
+    caches))``; ``counters`` is the group's (4,) int32 sparse-attention
+    work (``repro.kernels.indexing.SPARSE_COUNTERS``), counted by the
+    serving prefill (``return_cache``) and zero otherwise.
 
     Shared by the training/serving stacks AND the roofline cost model
     (dryrun compiles one group with identical remat/sharding to correct
@@ -136,17 +143,19 @@ def make_group_fn(
 
     def group_fn(x, gp):
         aux_total = jnp.zeros((), jnp.float32)
+        counters_total = jnp.zeros((len(SPARSE_COUNTERS),), jnp.int32)
         caches = {}
         for i, (mixer, ffn) in enumerate(layout):
-            x, aux, cache = _layer_apply(
+            x, aux, cache, counters = _layer_apply(
                 x, gp[f"l{i}"], cfg, mixer, ffn, positions, spec,
                 lengths, ssm_impl, return_cache, moe_parallel, sp_spec)
             aux_total = aux_total + aux
+            counters_total = counters_total + counters
             if return_cache:
                 caches[f"l{i}"] = cache
         if sp_spec is not None:
             x = jax.lax.with_sharding_constraint(x, sp_spec)
-        return x, (aux_total, caches)
+        return x, (aux_total, counters_total, caches)
 
     if remat:
         policy = {
@@ -177,15 +186,17 @@ def stack_apply(
     moe_parallel: MoEParallelism | None = None,
     sp_spec=None,
 ):
-    """Run the decoder stack.  Returns (hidden, aux) or (hidden, aux, cache)."""
+    """Run the decoder stack.  Returns (hidden, aux) or, with
+    ``return_cache``, (hidden, aux, cache, counters): ``counters`` is
+    (num_groups, 4) int32, each group's sparse-attention work."""
     group_fn = make_group_fn(
         cfg, positions, spec=spec, lengths=lengths,
         ssm_impl=ssm_impl, remat=remat, remat_policy=remat_policy,
         return_cache=return_cache, moe_parallel=moe_parallel,
         sp_spec=sp_spec)
-    x, (auxes, caches) = jax.lax.scan(group_fn, x, stacked)
+    x, (auxes, counters, caches) = jax.lax.scan(group_fn, x, stacked)
     if return_cache:
-        return x, jnp.sum(auxes), caches
+        return x, jnp.sum(auxes), caches, counters
     return x, jnp.sum(auxes)
 
 
@@ -334,6 +345,9 @@ def stack_chunk_prefill(
     serving engine enforces the alignment); ``None``/dense runs dense
     history attention.  ``live`` (() int32, optional) is the real-row
     count of a zero-padded final chunk.
+
+    Returns (hidden, cache, counters), ``counters`` (num_groups, 4) int32
+    as :func:`stack_apply`'s.
     """
     layout = cfg.group_layout()
     if cfg.use_mla or any(mixer != "attn" for mixer, _ in layout):
@@ -343,11 +357,13 @@ def stack_chunk_prefill(
     def group_fn(x, inp):
         gp, gc = inp
         new_gc = {}
+        counters_total = jnp.zeros((len(SPARSE_COUNTERS),), jnp.int32)
         for i, (mixer, ffn) in enumerate(layout):
             p = gp[f"l{i}"]
             h = rmsnorm(x, p["norm_mixer"], cfg.norm_eps)
-            h, nc = attn_lib.gqa_chunk_apply(
+            h, nc, counters = attn_lib.gqa_chunk_apply(
                 h, p["attn"], gc[f"l{i}"], cfg, pos, spec=spec, live=live)
+            counters_total = counters_total + counters
             new_gc[f"l{i}"] = nc
             x = x + h
             if ffn != "none":
@@ -357,7 +373,7 @@ def stack_chunk_prefill(
                 else:
                     h = mlp_apply(h, p["mlp"], cfg.mlp_act)
                 x = x + h
-        return x, new_gc
+        return x, (new_gc, counters_total)
 
-    x, new_cache = jax.lax.scan(group_fn, x, (stacked, cache))
-    return x, new_cache
+    x, (new_cache, counters) = jax.lax.scan(group_fn, x, (stacked, cache))
+    return x, new_cache, counters

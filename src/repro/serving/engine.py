@@ -50,6 +50,28 @@ length-truncated retirements, and the paged-subsystem counters
 (pages_in_use / pages_hwm, prefix_hits, shared_pages, chunked_prefills,
 preemptions, ...).  ``engine.snapshot()`` returns a self-consistent copy
 with the live gauges refreshed.
+
+Sparse prefill adds four counters, counted on the device from the very
+tables the sparse sweep consumed (``repro.kernels.indexing.
+sparse_counters``) and fetched with the first-token argmax, so no sync
+is added: ``stripes_kept`` (kept stripes over sequences, query heads,
+superblocks and layers), ``stripe_tiles_kept`` (occupied selection
+slots), ``sparse_slots_used`` (occupied slots, anchor slots included,
+once per query block that walks them) and ``sparse_slots_visited``
+(every slot the sweep's grid walks).  Dense waves add nothing.
+
+Every step is timed by spans (:mod:`repro.serving.tracing`), named in
+``SPAN_NAMES``: ``engine.step`` is the root; under it ``engine.admit``
+(with one ``engine.prefill`` per wave, whose children are
+``engine.prefill.dispatch`` — the model call up to its return: tracing,
+lowering, cache loads, enqueue — ``engine.prefill.sync``, the
+first-token fetch, and ``engine.prefill.store``, the page scatter),
+``engine.chunk`` per prefill chunk, ``engine.decode`` per position group
+(children ``engine.decode.pages``, ``engine.decode.dispatch``,
+``engine.decode.sync``) and ``engine.retire`` per finished request.
+Spans of one request carry its ``uid``.  They land on the profiler's host
+plane when a trace records, and always in an in-memory log that
+``engine.spans(t0, t1)`` returns.
 """
 
 from __future__ import annotations
@@ -64,14 +86,31 @@ import numpy as np
 
 from repro.core.config import AnchorConfig
 from repro.core.spec import AttentionSpec, resolve_attention_spec
+from repro.kernels.indexing import SPARSE_COUNTERS
 from repro.models import cache as cache_lib
 from repro.models import model as model_lib
 from repro.models.cache import NULL_PAGE, PagedKVLayout
 from repro.models.config import ModelConfig
+from repro.serving import tracing
 from repro.serving.kv_pool import PagePool
 from repro.serving.prefix_cache import PrefixCache
+from repro.serving.tracing import span
 
 CACHE_LAYOUTS = ("dense", "paged")
+SPAN_NAMES = (
+    "engine.step",
+    "engine.admit",
+    "engine.prefill",
+    "engine.prefill.dispatch",
+    "engine.prefill.sync",
+    "engine.prefill.store",
+    "engine.chunk",
+    "engine.decode",
+    "engine.decode.pages",
+    "engine.decode.dispatch",
+    "engine.decode.sync",
+    "engine.retire",
+)
 
 
 @dataclasses.dataclass
@@ -92,6 +131,7 @@ class _ChunkState:
     pos: int  # next chunk starts here (page-aligned)
     shared_pages: int  # leading pages satisfied by the prefix cache
     append_first: bool  # fresh request: append argmax of the last chunk
+    counters: Any = None  # sparse work of the chunks so far, on device
 
 
 class ServingEngine:
@@ -155,6 +195,8 @@ class ServingEngine:
             "cow_copies": 0,
             "prefix_evictions": 0,
             "rejections": 0,
+            # Sparse-prefill work (module doc), summed over layers.
+            **{name: 0 for name in SPARSE_COUNTERS},
         }
         self._rejected: list[Request] = []
 
@@ -243,6 +285,12 @@ class ServingEngine:
                 f"request {req.uid}: {len(req.prompt)} prompt tokens do not "
                 f"fit max_len={self.max_len}")
         self.queue.append(req)
+
+    def spans(self, t0: float | None = None,
+              t1: float | None = None) -> list[tracing.Span]:
+        """The span log (module doc) of the window ``[t0, t1]``
+        (``time.perf_counter()`` seconds; either bound may be open)."""
+        return tracing.spans(t0, t1)
 
     @property
     def idle(self) -> bool:
@@ -449,20 +497,27 @@ class ServingEngine:
         toks = np.zeros((len(reqs), n_pad), np.int32)
         for j, seq in enumerate(seqs):
             toks[j, : lens[j]] = seq
-        lengths = jnp.asarray(lens, jnp.int32)
-        logits, pcache = model_lib.prefill(
-            self.params, jnp.asarray(toks), self.cfg,
-            spec=spec, lengths=lengths)
+        with span("engine.prefill", uids=tuple(r.uid for r in reqs),
+                  n_pad=n_pad, tokens=tuple(lens)) as sp:
+            with span("engine.prefill.dispatch"):
+                logits, (pcache, counters) = model_lib.prefill(
+                    self.params, jnp.asarray(toks), self.cfg,
+                    spec=spec, lengths=jnp.asarray(lens, jnp.int32),
+                    return_counters=True)
+            with span("engine.prefill.sync"):  # one device->host transfer
+                first_toks, counters = jax.device_get(
+                    (jnp.argmax(logits, axis=-1), counters))
+            self._count_sparse(counters, sp)
+            with span("engine.prefill.store"):
+                if self.cache_layout == "paged":
+                    self._store_prefill_pages(slots, meta, n_pad, pcache)
+                else:
+                    self.cache = self._insert_cache(
+                        self.cache, pcache, jnp.asarray(slots, jnp.int32))
         self.stats["prefill_requests"] += len(reqs)
         if len(reqs) > 1:
             self.stats["batched_prefills"] += 1
         self.stats["padded_tokens"] += len(reqs) * n_pad - sum(lens)
-        first_toks = np.asarray(jnp.argmax(logits, axis=-1))  # one sync
-        if self.cache_layout == "paged":
-            self._store_prefill_pages(slots, meta, n_pad, pcache)
-        else:
-            self.cache = self._insert_cache(
-                self.cache, pcache, jnp.asarray(slots, jnp.int32))
         for j, (slot, req) in enumerate(zip(slots, reqs)):
             if not req.generated:
                 # Preempted requests already hold their tokens: the ones
@@ -475,6 +530,14 @@ class ServingEngine:
             self.slot_req[slot] = req
             self.slot_pos[slot] = lens[j]
             self._slot_plen[slot] = lens[j]
+
+    def _count_sparse(self, counters: np.ndarray, sp: span) -> None:
+        """Add one prefill's fetched (num_groups, 4) sparse work counts to
+        ``stats`` and to its span's attributes."""
+        totals = np.asarray(counters, np.int64).sum(axis=0)
+        for name, value in zip(SPARSE_COUNTERS, totals.tolist()):
+            self.stats[name] += value
+            sp.attrs[name] = value
 
     def _store_prefill_pages(
         self,
@@ -501,10 +564,10 @@ class ServingEngine:
 
     # ------------------------------------------------- chunked prefill ----
 
-    def _prefill_chunk_step(self, slot: int) -> None:
+    def _prefill_chunk_step(self, slot: int, sp: span) -> None:
         """Run ONE chunk of an in-flight chunked prefill (engine steps
         interleave these with decode, so long prompts never head-of-line
-        block the decode batch)."""
+        block the decode batch); ``sp`` is its ``engine.chunk`` span."""
         st = self._chunking[slot]
         ps = self.pool.page_size
         chunk = self.chunk_tokens
@@ -514,9 +577,12 @@ class ServingEngine:
         toks[0, : c1 - c0] = st.tokens[c0:c1]
         pt_row = jnp.asarray(self._pt[slot:slot + 1])
         view = self._gather_view(self.cache, pt_row)
-        logits, view = self._chunk(
+        logits, view, counters = self._chunk(
             self.params, jnp.asarray(toks), view, jnp.asarray(c0, jnp.int32),
             jnp.asarray(c1 - c0, jnp.int32))
+        # Summed on the device, fetched with the first token.
+        st.counters = (counters if st.counters is None
+                       else st.counters + counters)
         # Scatter back only this chunk's pages, minus prefix-shared ones
         # and the padding tail.
         prompt_pages = self.pool.pages_for_tokens(len(st.tokens))
@@ -533,8 +599,11 @@ class ServingEngine:
             return
         # Final chunk: sample the first token, hand the slot to decode.
         req = st.req
+        first, counters = jax.device_get(
+            (jnp.argmax(logits[0, c1 - c0 - 1]), st.counters))
+        self._count_sparse(counters, sp)
         if st.append_first:
-            req.generated.append(int(jnp.argmax(logits[0, c1 - c0 - 1])))
+            req.generated.append(int(first))
         if self.prefix is not None:
             full = len(st.tokens) // ps
             self.prefix.insert(st.tokens, self._pt[slot, :full], "chunked")
@@ -553,12 +622,21 @@ class ServingEngine:
         redundant per-token replay)."""
         prompt = jnp.asarray(req.prompt, jnp.int32)[None]
         n = prompt.shape[1]
-        logits, pcache = model_lib.prefill(
-            self.params, prompt, self.cfg, spec=self._single_spec(n))
-        self.cache = self._insert_cache(
-            self.cache, pcache, jnp.asarray([slot], jnp.int32))
+        with span("engine.prefill", uids=(req.uid,), n_pad=n,
+                  tokens=(n,)) as sp:
+            with span("engine.prefill.dispatch"):
+                logits, (pcache, counters) = model_lib.prefill(
+                    self.params, prompt, self.cfg, spec=self._single_spec(n),
+                    return_counters=True)
+            with span("engine.prefill.sync"):
+                first, counters = jax.device_get(
+                    (jnp.argmax(logits[0]), counters))
+            self._count_sparse(counters, sp)
+            with span("engine.prefill.store"):
+                self.cache = self._insert_cache(
+                    self.cache, pcache, jnp.asarray([slot], jnp.int32))
         if not req.generated:
-            req.generated.append(int(jnp.argmax(logits[0])))
+            req.generated.append(int(first))
         self.slot_req[slot] = req
         self.slot_pos[slot] = n
         self._slot_plen[slot] = n
@@ -737,9 +815,16 @@ class ServingEngine:
         """One engine iteration: admit, advance one chunk of every
         in-flight chunked prefill, batch-decode, retire.  Returns newly
         finished requests."""
-        self._admit()
+        with span("engine.step"):
+            return self._step()
+
+    def _step(self) -> list[Request]:
+        with span("engine.admit"):
+            self._admit()
         for slot in sorted(self._chunking):
-            self._prefill_chunk_step(slot)
+            st = self._chunking[slot]
+            with span("engine.chunk", uid=st.req.uid, pos=st.pos) as sp:
+                self._prefill_chunk_step(slot, sp)
         active = [s for s in range(self.max_batch) if self.slot_req[s] is not None]
         finished: list[Request] = self._rejected
         self._rejected = []
@@ -751,31 +836,46 @@ class ServingEngine:
         for s in active:
             by_pos.setdefault(int(self.slot_pos[s]), []).append(s)
         for pos, slots in by_pos.items():
-            if self.cache_layout == "paged":
+            with span("engine.decode", pos=pos) as sp:
+                done = self._decode_group(pos, slots, sp)
+            for s in done:
+                finished.append(self.slot_req[s])
+                with span("engine.retire", uid=self.slot_req[s].uid):
+                    self._retire_slot(s)
+        return finished
+
+    def _decode_group(self, pos: int, slots: list[int], sp: span) -> list[int]:
+        """Decode one position group; returns the slots whose request
+        finished (marked done, not yet retired)."""
+        if self.cache_layout == "paged":
+            with span("engine.decode.pages"):
                 # A grow may preempt a slot of ANY group (even one already
                 # grown in this loop) — filter on live occupancy before
                 # and after, not just on the grow result.
                 slots = [s for s in slots if self.slot_req[s] is not None
                          and self._grow_page(s, pos)]
                 slots = [s for s in slots if self.slot_req[s] is not None]
-                if not slots:
-                    continue
-            toks = np.zeros(self.max_batch, np.int32)
-            act = np.zeros(self.max_batch, bool)
-            for s in slots:
-                # The input at position p is generated[p - prompt_len].
-                # For a fresh request that is always generated[-1]; a
-                # preempted request re-enters with its position reset to
-                # the prompt end and *replays* its known tokens through
-                # ordinary decode steps — bit-exact cache reconstruction
-                # under any attention config (sampling suppressed below).
-                toks[s] = self.slot_req[s].generated[
-                    pos - int(self._slot_plen[s])]
-                act[s] = True
-            # `act` restricts cache/state writes to this position group —
-            # without it the write at `pos` would corrupt slots whose own
-            # position is past it (mixed-position batches are the norm
-            # with ragged batched prefill).
+        sp.attrs["uids"] = tuple(self.slot_req[s].uid for s in slots)
+        done: list[int] = []
+        if not slots:
+            return done
+        toks = np.zeros(self.max_batch, np.int32)
+        act = np.zeros(self.max_batch, bool)
+        for s in slots:
+            # The input at position p is generated[p - prompt_len].
+            # For a fresh request that is always generated[-1]; a
+            # preempted request re-enters with its position reset to
+            # the prompt end and *replays* its known tokens through
+            # ordinary decode steps — bit-exact cache reconstruction
+            # under any attention config (sampling suppressed below).
+            toks[s] = self.slot_req[s].generated[
+                pos - int(self._slot_plen[s])]
+            act[s] = True
+        # `act` restricts cache/state writes to this position group —
+        # without it the write at `pos` would corrupt slots whose own
+        # position is past it (mixed-position batches are the norm
+        # with ragged batched prefill).
+        with span("engine.decode.dispatch"):
             if self.cache_layout == "paged":
                 logits, self.cache = self._decode_paged(
                     self.params, self.cache, jnp.asarray(toks),
@@ -784,22 +884,22 @@ class ServingEngine:
                 logits, self.cache = self._decode(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(pos), jnp.asarray(act))
-            self.stats["decode_steps"] += 1
+        self.stats["decode_steps"] += 1
+        with span("engine.decode.sync"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            for s in slots:
-                req = self.slot_req[s]
-                self.slot_pos[s] = pos + 1
-                if pos - int(self._slot_plen[s]) < len(req.generated) - 1:
-                    continue  # replaying a preempted request: token known
-                req.generated.append(int(nxt[s]))
-                hit_len = self.slot_pos[s] >= self.max_len - 1
-                if hit_len:
-                    self.stats["length_truncations"] += 1
-                if len(req.generated) >= req.max_new_tokens or hit_len:
-                    req.done = True
-                    finished.append(req)
-                    self._retire_slot(s)
-        return finished
+        for s in slots:
+            req = self.slot_req[s]
+            self.slot_pos[s] = pos + 1
+            if pos - int(self._slot_plen[s]) < len(req.generated) - 1:
+                continue  # replaying a preempted request: token known
+            req.generated.append(int(nxt[s]))
+            hit_len = self.slot_pos[s] >= self.max_len - 1
+            if hit_len:
+                self.stats["length_truncations"] += 1
+            if len(req.generated) >= req.max_new_tokens or hit_len:
+                req.done = True
+                done.append(s)
+        return done
 
     def run_to_completion(self, max_iters: int = 10_000) -> list[Request]:
         done: list[Request] = []
