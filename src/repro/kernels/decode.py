@@ -7,6 +7,11 @@ iteration A3 (scores never touch HBM).
 
 Grid: ``(batch*heads, S // block_s)`` with the cache axis sequential.
 ``cache_len`` arrives via scalar prefetch (SMEM).
+
+``paged_flash_decode`` reads the serving engine's page pool instead:
+grid ``(batch, ceil(n_pages / pages_per_step))``, one step per block of
+pages for every KV head and its whole query group, steps past
+``cache_len`` idle.
 """
 
 from __future__ import annotations
@@ -140,10 +145,41 @@ dispatch.register("flash_decode", "pallas_tpu")(
 # ------------------------------------------------------ paged flash decode ----
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         ms_ref, ls_ref, acc_ref, *, page_size, scale):
-    del pt_ref  # consumed by the BlockSpec index maps
-    j = pl.program_id(1)
+# Positions one grid step of ``paged_flash_decode`` covers, and the VMEM
+# its double-buffered K and V pages may take.
+_STEP_POSITIONS = 512
+_KV_BUFFER_BYTES = 8 << 20
+
+
+def pages_per_step(page_size: int, n_pages: int, hkv: int, d: int,
+                   itemsize: int) -> int:
+    """Pages ``paged_flash_decode`` loads per grid step: about
+    ``_STEP_POSITIONS`` positions, no more than the table holds, and few
+    enough that two buffers each of K and V fit ``_KV_BUFFER_BYTES``."""
+    page_bytes = hkv * page_size * d * itemsize
+    return max(1, min(_STEP_POSITIONS // page_size, n_pages,
+                      _KV_BUFFER_BYTES // (4 * page_bytes)))
+
+
+def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, ms_ref, ls_ref, acc_ref, *,
+                         scale):
+    b, j = pl.program_id(0), pl.program_id(1)
+    n_steps = pl.num_programs(1)
+    _, pages, hkv, page_size, d = k_buf.shape
+    block = pages * page_size
+    cache_len = len_ref[0]
+    slot = j % 2
+
+    def copies(step, slot):
+        """DMAs of step ``step``'s pages (every KV head of each) into
+        buffer ``slot``; the table is flat, ``pages`` entries a step."""
+        base = (b * n_steps + step) * pages
+        return [pltpu.make_async_copy(src.at[pt_ref[base + i]],
+                                      buf.at[slot, i], sems.at[kv, slot])
+                for kv, (src, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))
+                for i in range(pages)]
 
     @pl.when(j == 0)
     def _init():
@@ -151,30 +187,50 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         ls_ref[...] = jnp.zeros_like(ls_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # (1, D)
-    k = k_ref[0, 0].astype(jnp.float32)  # (page_size, D)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (1, page_size)
-    # Logical column of each lane: the page table maps logical page j onto
-    # an arbitrary physical page, but the *positions* it holds are always
-    # [j*page_size, (j+1)*page_size) — trash/unassigned pages sit at
-    # logical positions >= cache_len and are masked out here.
-    col = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = col < len_ref[0]
-    s = jnp.where(valid, s, _NEG_INF)
-    m_prev = ms_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(valid, p, 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    ls_ref[...] = ls_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ms_ref[...] = m_new
+        @pl.when(cache_len > 0)
+        def _first():
+            for c in copies(0, 0):
+                c.start()
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    # Steps at or past the cache length move no bytes and do no work.
+    @pl.when((j + 1) * block < cache_len)
+    def _prefetch():
+        for c in copies(j + 1, 1 - slot):
+            c.start()
+
+    @pl.when(j * block < cache_len)
+    def _step():
+        for c in copies(j, slot):
+            c.wait()
+        # Logical position of each column / V row: table entry j*pages+i
+        # holds positions [(j*pages+i)*page_size, ...) whatever physical
+        # page it names; those >= cache_len (the partial last page, null
+        # or stale pages, possibly NaN) are masked, in V's own layout too
+        # so that 0 * NaN never reaches acc.
+        g = q_ref.shape[2]
+        col = j * block + jax.lax.broadcasted_iota(jnp.int32, (g, block), 1)
+        valid = col < cache_len
+        v_row = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, d), 0)
+        for h in range(hkv):  # the G query rows of KV head h in one product
+            q = q_ref[0, h].astype(jnp.float32)  # (G, D)
+            k = k_buf[slot, :, h].astype(jnp.float32).reshape(block, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, block)
+            s = jnp.where(valid, s, _NEG_INF)
+            m_prev = ms_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            ls_ref[h] = ls_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            v = v_buf[slot, :, h].astype(jnp.float32).reshape(block, d)
+            v = jnp.where(v_row < cache_len, v, 0.0)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ms_ref[h] = m_new
+
+    @pl.when(j == n_steps - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] / jnp.maximum(ls_ref[...], 1e-30)).astype(
             o_ref.dtype)
@@ -192,13 +248,14 @@ def paged_flash_decode(
 ) -> jnp.ndarray:
     """One-token decode attention over a *paged* KV cache.
 
-    The page-table indirection lives in the BlockSpec index map: logical
-    KV tile ``j`` of batch row ``b`` is DMA'd from physical page
-    ``page_tables[b, j]`` of the shared pool — the serving-side twin of
-    the paper's discrete KV position loading (the kernel streams scattered
-    pages exactly like the sparse path streams scattered stripes).
-    ``page_tables`` arrives via scalar prefetch so the indices are on-core
-    before the grid body runs.
+    Each grid step DMAs a block of ``pages_per_step`` pages, every KV
+    head of each, from the shared pool into VMEM (the serving-side twin
+    of the paper's discrete KV position loading), while the next block
+    loads into the other buffer.  The ``G = Hq // Hkv`` query heads of a
+    KV head score its pages in one ``(G, D) x (D, block)`` product, so
+    each page is read once per token.  Steps whose first position is at
+    or past ``cache_len`` load and compute nothing.  ``page_tables`` and
+    ``cache_len`` arrive via scalar prefetch.
 
     q: (B, Hq, 1, D); pages: (P, Hkv, page_size, D);
     page_tables: (B, n_pages) int32 physical page ids (0 = null page);
@@ -206,47 +263,52 @@ def paged_flash_decode(
     """
     b, hq, _, d = q.shape
     hkv, page_size = k_pages.shape[1], k_pages.shape[2]
+    g = hq // hkv
     n_pages = page_tables.shape[1]
+    pages = pages_per_step(page_size, n_pages, hkv, d,
+                           k_pages.dtype.itemsize)
+    n_steps = -(-n_pages // pages)
     scale = 1.0 / (d ** 0.5)
 
-    qf = q.reshape(b * hq, 1, d)
-    pt = page_tables.astype(jnp.int32)
-    len_arr = jnp.full((1,), cache_len, jnp.int32)
+    qg = q.reshape(b, hkv, g, d)
+    # Flat table padded to whole steps with the null page; positions past
+    # the table are masked like those past the cache.
+    pt = jnp.pad(page_tables.astype(jnp.int32),
+                 ((0, 0), (0, n_steps * pages - n_pages))).reshape(-1)
+    len_arr = jnp.full((1,), jnp.minimum(cache_len, n_pages * page_size),
+                       jnp.int32)
 
-    def q_index(bh, j, pt_ref, len_ref):
-        return bh, 0, 0
+    def row_index(i, j, pt_ref, len_ref):
+        return i, 0, 0, 0
 
-    def kv_index(bh, j, pt_ref, len_ref):
-        # Page-table indirection + the shared GQA fold: physical page id
-        # from the scalar-prefetched table, KV head from kv_head_index
-        # (modulo the batch term, which the page axis already encodes).
-        return pt_ref[bh // hq, j], kv_head_index(bh % hq, hq, hkv), 0, 0
-
-    kernel = functools.partial(
-        _paged_decode_kernel, page_size=page_size, scale=scale)
+    buf = pltpu.VMEM((2, pages, hkv, page_size, d), k_pages.dtype)
+    kernel = functools.partial(_paged_decode_kernel, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * hq, n_pages),
+        grid=(b, n_steps),
         in_specs=[
-            pl.BlockSpec((1, 1, d), q_index),
-            pl.BlockSpec((1, 1, page_size, d), kv_index),
-            pl.BlockSpec((1, 1, page_size, d), kv_index),
+            pl.BlockSpec((1, hkv, g, d), row_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), q_index),
+        out_specs=pl.BlockSpec((1, hkv, g, d), row_index),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            buf, buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pt, len_arr, qf, k_pages, v_pages)
+        name="paged_flash_decode",
+    )(pt, len_arr, qg, k_pages, v_pages)
     return out.reshape(b, hq, 1, d)
 
 

@@ -34,6 +34,7 @@ import pytest
 from repro.core import AnchorConfig, AttentionSpec
 from repro.kernels import indexing
 from repro.kernels import ops as kernel_ops
+from repro.kernels.decode import pages_per_step
 from repro.kernels.xla import (
     sparse_attention_gathered,
     staged_anchor_stats,
@@ -44,6 +45,13 @@ from repro.kernels.xla import (
 CFG = AnchorConfig(block_q=32, block_kv=32, step=2, theta=3.0)
 B, HQ, HKV, N, D = 2, 4, 2, 256, 32
 BACKENDS = ("xla", "pallas_interpret")
+# Paged decode: (Hq, Hkv) of MHA, internlm2 and Yi at cache lengths around
+# the kernel's page and block of pages, and the original HQ/HKV case.
+DECODE_HEADS = [(8, 8), (16, 8), (32, 4)]
+DECODE_LENS = ("one", "page_minus_one", "one_step", "one_step_plus_one",
+               "whole_table")
+DECODE_CASES = ([(hq, hkv, n) for hq, hkv in DECODE_HEADS for n in DECODE_LENS]
+                + [(HQ, HKV, "table4_len50")])
 
 
 def _qkv(seed, b=B, hq=HQ, hkv=HKV, n=N, d=D):
@@ -205,20 +213,49 @@ class TestRepeatExpandedParity:
                                       block_s=32, backend=backend)
         _check_decode(backend, out, ref)
 
+    @pytest.mark.parametrize("hq,hkv,case", DECODE_CASES,
+                             ids=[f"{a}q{b}kv-{c}" for a, b, c in DECODE_CASES])
     @pytest.mark.parametrize("backend", BACKENDS)
-    def test_paged_flash_decode(self, backend):
-        ks = jax.random.split(jax.random.PRNGKey(7), 3)
-        n_pages, page_size = 9, 16
-        q = jax.random.normal(ks[0], (B, HQ, 1, D))
-        kp = jax.random.normal(ks[1], (n_pages, HKV, page_size, D))
-        vp = jax.random.normal(ks[2], (n_pages, HKV, page_size, D))
-        pt = jnp.asarray([[1, 3, 5, 7], [2, 4, 6, 8]], jnp.int32)
-        out = kernel_ops.paged_flash_decode(q, kp, vp, pt, jnp.asarray(50),
+    def test_paged_flash_decode(self, backend, hq, hkv, case):
+        """Grouped ≡ expanded on each backend, and the kernel ≡ the xla
+        twin, at lengths around the kernel's block of pages."""
+        q, kp, vp, pt, clen = _paged_case(hq, hkv, case)
+        out = kernel_ops.paged_flash_decode(q, kp, vp, pt, clen,
                                             backend=backend)
-        kr, vr = (jnp.repeat(x, HQ // HKV, axis=1) for x in (kp, vp))
-        ref = kernel_ops.paged_flash_decode(q, kr, vr, pt, jnp.asarray(50),
+        kr, vr = (jnp.repeat(x, hq // hkv, axis=1) for x in (kp, vp))
+        ref = kernel_ops.paged_flash_decode(q, kr, vr, pt, clen,
                                             backend=backend)
         _check_decode(backend, out, ref)
+        if backend != "xla":
+            twin = kernel_ops.paged_flash_decode(q, kp, vp, pt, clen,
+                                                 backend="xla")
+            _check_decode(backend, out, twin)
+
+
+def _paged_case(hq, hkv, case):
+    """q, pool, table and cache length of one paged decode case: the
+    original 4-page rows at length 50, or a shuffled 40-page table at
+    one of ``DECODE_LENS``."""
+    if case == "table4_len50":
+        ks = jax.random.split(jax.random.PRNGKey(7), 3)
+        q = jax.random.normal(ks[0], (B, hq, 1, D))
+        kp = jax.random.normal(ks[1], (9, hkv, 16, D))
+        vp = jax.random.normal(ks[2], (9, hkv, 16, D))
+        pt = jnp.asarray([[1, 3, 5, 7], [2, 4, 6, 8]], jnp.int32)
+        return q, kp, vp, pt, jnp.asarray(50)
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    n_pages, page_size = 40, 16
+    pool = (1 + B * n_pages, hkv, page_size, D)
+    q = jax.random.normal(ks[0], (B, hq, 1, D))
+    kp = jax.random.normal(ks[1], pool)
+    vp = jax.random.normal(ks[2], pool)
+    pt = (1 + jax.random.permutation(ks[3], B * n_pages)).astype(
+        jnp.int32).reshape(B, n_pages)
+    step = page_size * pages_per_step(page_size, n_pages, hkv, D, 4)
+    clen = jnp.asarray({"one": 1, "page_minus_one": page_size - 1,
+                        "one_step": step, "one_step_plus_one": step + 1,
+                        "whole_table": n_pages * page_size}[case])
+    return q, kp, vp, pt, clen
 
 
 # -------------------------------------------------- jaxpr inspection ----

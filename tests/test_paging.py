@@ -16,6 +16,7 @@ import pytest
 from repro.configs import get_reduced_config
 from repro.core import AnchorConfig, AttentionSpec
 from repro.kernels import ops as kernel_ops
+from repro.kernels.decode import pages_per_step
 from repro.models import model as model_lib
 from repro.models.cache import PagedKVLayout, gather_pages, supports_paged
 from repro.models.layers import decode_attention
@@ -149,33 +150,92 @@ class TestPrefixCache:
 # ---------------------------------------------- paged_flash_decode parity ----
 
 
-class TestPagedFlashDecode:
-    def _setup(self, seed=0, b=3, hq=4, hkv=2, d=16, ps=8, n_pages=5, pool_p=12):
-        rng = np.random.default_rng(seed)
-        k_pages = jnp.asarray(rng.normal(size=(pool_p, hkv, ps, d)), jnp.float32)
-        v_pages = jnp.asarray(rng.normal(size=(pool_p, hkv, ps, d)), jnp.float32)
-        pt = jnp.asarray(rng.integers(1, pool_p, size=(b, n_pages)), jnp.int32)
-        q = jnp.asarray(rng.normal(size=(b, hq, 1, d)), jnp.float32)
-        return q, k_pages, v_pages, pt
+# The kernel's own cases: (Hq, Hkv) of MHA, internlm2's group of 2 and
+# Yi's group of 8, B = 2, page 16 over a shuffled 40-page table, at cache
+# lengths around a page and the kernel's block of pages; and at one step
+# + 1 with both rows sharing their first 20 physical pages, as prefix
+# sharing lays them out.
+PARITY_HEADS = [(8, 8), (16, 8), (32, 4)]
+PARITY_PAGE, PARITY_PAGES, SHARED_PAGES = 16, 40, 20
+CACHE_LEN_CASES = ("one", "page_minus_one", "one_step", "one_step_plus_one",
+                   "whole_table")
+KERNEL_CASES = [(hq, hkv, case) for hq, hkv in PARITY_HEADS
+                for case in CACHE_LEN_CASES + ("shared_prefix",)]
+# The original cases: page 8, B = 3, 4/2 heads, ids drawn with replacement
+# from an 11-page pool, so rows and positions repeat physical pages.
+REPEATS = (4, 2)
+PARITY_CASES = KERNEL_CASES + [(*REPEATS, n) for n in (1, 17, 40)]
+NULL_CASES = KERNEL_CASES + [(*REPEATS, 20)]
 
+
+def _case_id(case):
+    hq, hkv, length = case
+    return f"{hq}q{hkv}kv-" + (f"repeats_len{length}"
+                               if (hq, hkv) == REPEATS else length)
+
+
+def _repeats_table(seed, b=3, hq=4, hkv=2, d=16, ps=8, n_pages=5, pool_p=12):
+    rng = np.random.default_rng(seed)
+    k_pages = jnp.asarray(rng.normal(size=(pool_p, hkv, ps, d)), jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(pool_p, hkv, ps, d)), jnp.float32)
+    pt = jnp.asarray(rng.integers(1, pool_p, size=(b, n_pages)), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, hq, 1, d)), jnp.float32)
+    return q, k_pages, v_pages, pt
+
+
+def _parity_case(hq, hkv, length, seed, b=2, d=16):
+    """q, pool, table and cache length of one case, and the page size.
+    ``length`` is a cache length for the repeated table, else one of
+    ``CACHE_LEN_CASES`` or ``"shared_prefix"``; a step is the kernel's own
+    block of pages."""
+    if (hq, hkv) == REPEATS:
+        return (*_repeats_table(seed), jnp.asarray(length, jnp.int32)), 8
+    rng = np.random.default_rng(seed)
+    pool = (1 + b * PARITY_PAGES, hkv, PARITY_PAGE, d)
+    kp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    pt = jnp.asarray(1 + rng.permutation(b * PARITY_PAGES), jnp.int32)
+    pt = pt.reshape(b, PARITY_PAGES)
+    q = jnp.asarray(rng.normal(size=(b, hq, 1, d)), jnp.float32)
+    step = PARITY_PAGE * pages_per_step(PARITY_PAGE, PARITY_PAGES, hkv, d, 4)
+    assert step < PARITY_PAGE * PARITY_PAGES  # the table spans two steps
+    if length == "shared_prefix":
+        pt = pt.at[1:, :SHARED_PAGES].set(pt[0, :SHARED_PAGES])
+        assert SHARED_PAGES * PARITY_PAGE < step + 1
+    clen = {"one": 1, "page_minus_one": PARITY_PAGE - 1, "one_step": step,
+            "one_step_plus_one": step + 1, "shared_prefix": step + 1,
+            "whole_table": PARITY_PAGE * PARITY_PAGES}[length]
+    return (q, kp, vp, pt, jnp.asarray(clen, jnp.int32)), PARITY_PAGE
+
+
+def _unread_pages(pool_pages, pt, clen, page):
+    """Pool pages that no row reads below the cache length."""
+    live = np.asarray(pt[:, :-(-int(clen) // page)]).reshape(-1)
+    return jnp.asarray(np.setdiff1d(np.arange(pool_pages), live), jnp.int32)
+
+
+class TestPagedFlashDecode:
     def test_xla_matches_gathered_dense_decode_exactly(self):
-        q, kp, vp, pt = self._setup()
+        q, kp, vp, pt = _repeats_table(0)
         clen = jnp.asarray(29, jnp.int32)
         ref = decode_attention(q, gather_pages(kp, pt), gather_pages(vp, pt),
                                clen)
         out = kernel_ops.paged_flash_decode(q, kp, vp, pt, clen, backend="xla")
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
 
-    def test_pallas_interpret_parity(self):
-        q, kp, vp, pt = self._setup(seed=1)
-        for clen in (1, 17, 40):
-            ref = kernel_ops.paged_flash_decode(
-                q, kp, vp, pt, jnp.asarray(clen, jnp.int32), backend="xla")
-            out = kernel_ops.paged_flash_decode(
-                q, kp, vp, pt, jnp.asarray(clen, jnp.int32),
-                backend="pallas_interpret")
-            np.testing.assert_allclose(
-                np.asarray(ref), np.asarray(out), atol=2e-6, rtol=1e-5)
+    @pytest.mark.parametrize("hq,hkv,length", PARITY_CASES,
+                             ids=map(_case_id, PARITY_CASES))
+    def test_pallas_interpret_parity(self, hq, hkv, length):
+        """Pool pages that no row reads below its length, stale pages past
+        it among them, hold NaN for the kernel."""
+        (q, kp, vp, pt, clen), page = _parity_case(hq, hkv, length, seed=1)
+        ref = kernel_ops.paged_flash_decode(q, kp, vp, pt, clen, backend="xla")
+        dead = _unread_pages(kp.shape[0], pt, clen, page)
+        out = kernel_ops.paged_flash_decode(
+            q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan), pt, clen,
+            backend="pallas_interpret")
+        np.testing.assert_allclose(
+            np.asarray(ref), np.asarray(out), atol=2e-6, rtol=1e-5)
 
     def test_registered_backends(self):
         from repro.kernels import dispatch
@@ -183,16 +243,23 @@ class TestPagedFlashDecode:
         assert dispatch.registered_backends("paged_flash_decode") == [
             "pallas_interpret", "pallas_tpu", "xla"]
 
-    def test_null_page_entries_are_masked(self):
-        """Unassigned table slots (page 0) beyond cache_len never leak."""
-        q, kp, vp, pt = self._setup(seed=2)
-        pt = pt.at[:, 3:].set(0)  # last two logical pages unassigned
-        clen = jnp.asarray(20, jnp.int32)  # < 3 pages worth
+    @pytest.mark.parametrize("hq,hkv,length", NULL_CASES,
+                             ids=map(_case_id, NULL_CASES))
+    def test_null_page_entries_are_masked(self, hq, hkv, length):
+        """Unassigned table slots (page 0) beyond cache_len never leak,
+        on either backend, with the null page holding NaN for the kernel
+        and junk for the xla twin."""
+        (q, kp, vp, pt, clen), page = _parity_case(hq, hkv, length, seed=2)
+        pt = pt.at[:, -(-int(clen) // page):].set(0)
         ref = kernel_ops.paged_flash_decode(q, kp, vp, pt, clen, backend="xla")
-        junk = kp.at[0].set(1e4)  # poison the trash page
+        junk = kernel_ops.paged_flash_decode(
+            q, kp.at[0].set(1e4), vp.at[0].set(-1e4), pt, clen, backend="xla")
+        np.testing.assert_array_equal(np.asarray(ref), np.asarray(junk))
         out = kernel_ops.paged_flash_decode(
-            q, junk, vp.at[0].set(-1e4), pt, clen, backend="xla")
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+            q, kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan), pt, clen,
+            backend="pallas_interpret")
+        np.testing.assert_allclose(
+            np.asarray(ref), np.asarray(out), atol=2e-6, rtol=1e-5)
 
 
 class TestFlashDecodeBlockS:
