@@ -39,18 +39,19 @@ def main(argv=None) -> int:
     p.add_argument("--dry-run", action="store_true")
     args = p.parse_args(argv)
     c = harness.load_cell(Path(args.benchmark), args.workload)
-    conf, limits = c["conf"], c["limits"]
+    conf, limits, family = c["conf"], c["limits"], c["family"]
     seconds = args.seconds or float(c["bench"]["run_seconds"])
     if not args.dry_run:
         harness.enable_compile_cache()
     import jax
 
-    mcfg = build.model_config(conf)
-    arch, anchor = build.arch_of(conf), build.anchor_of(conf)
+    mcfg = family.model_config(conf)
+    arch, anchor = family.arch(conf), build.anchor_of(conf)
     controls = {int(s) for s in args.control_seeds.split(",") if s}
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()
-        params = build.make_weights(arch, conf.get("torch_dtype", "bfloat16"), seed)
+        params = family.make_weights(arch, conf.get("torch_dtype", "bfloat16"),
+                                     seed)
         engine = build.make_engine(params, mcfg, conf,
                                    backend="xla" if args.dry_run else None)
         traffic = Traffic(c["traffic"], seed, arch["vocab"])
@@ -64,7 +65,8 @@ def main(argv=None) -> int:
             if control and seed not in controls:
                 continue
             correct, checks, sample = harness.check(
-                params, arch, anchor, reqs, limits, seed, control=control)
+                family, params, arch, anchor, reqs, limits, seed,
+                control=control)
             row[name] = {"correct": correct,
                          **{k: v["value"] for k, v in checks.items()}}
             row["sampled"] = [r.prompt_len for r in sample]

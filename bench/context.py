@@ -1,13 +1,14 @@
-"""What a per-layer metric's reader gets: the window's record, the
-shapes, the peaks and the reduced trace, with the work done in the
-window gathered once."""
+"""What a per-layer metric's reader gets: the window's record, the model
+family (whose counts it calls), the shapes, the peaks and the reduced
+trace, with the work done in the window gathered once."""
 
 from __future__ import annotations
 
 
 class RunContext:
-    def __init__(self, rec: dict, arch: dict, anchor: dict, peaks: dict | None,
-                 trace: dict | None):
+    def __init__(self, rec: dict, family, arch: dict, anchor: dict,
+                 peaks: dict | None, trace: dict | None):
+        self.family = family
         self.arch, self.anchor, self.peaks, self.trace = arch, anchor, peaks, trace
         self.t0, self.t_close = rec["t0"], rec["t_close"]
         self.window_s = self.t_close - self.t0

@@ -8,11 +8,11 @@ the traffic uses is served once.  The window then drives
 traffic mix; a token counts as delivered when the ``step()`` call that
 produced it returns.  After the window the requests still in flight are
 finished untimed, the engine is freed, and a sample of the finished
-requests (the longest among them) is compared with the reference
-(``bench/reference.py``).  With ``--control`` the float8 control takes
-the program's place in that comparison, so the run has to read
-``correct: false`` (calibration and tests; the benchmark's own runs
-never pass it).
+requests (the longest among them) is compared with the plain reference
+of the configuration's model family (``bench/families/<family>.py``).
+With ``--control`` the float8 control takes the program's place in that
+comparison, so the run has to read ``correct: false`` (calibration and
+tests; the benchmark's own runs never pass it).
 """
 
 from __future__ import annotations
@@ -24,6 +24,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import time
@@ -67,10 +68,12 @@ def load_cell(bench_path: Path, workload: str) -> dict:
     cell = _find(bench["workloads"], workload, "workload")
     entry = _find(bench["configs"], cell["config"], "configuration")
     bench_dir = base / bench["paths"][0]
+    conf = load_json(base / entry["file"])
     return {
         "bench": bench,
         "cell": cell,
-        "conf": load_json(base / entry["file"]),
+        "conf": conf,
+        "family": load_family(bench_dir / "families", conf, entry["file"]),
         "traffic": load_json(bench_dir / "traffic" / f"{cell['traffic']}.json"),
         "limits": load_json(bench_dir / "cells" / f"{cell['name']}.json"),
         "metrics_dir": bench_dir / "metrics",
@@ -250,15 +253,56 @@ def end_to_end(rec: dict, setup_s: float) -> dict:
     return out
 
 
+def _load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(families_dir: Path, conf: dict, conf_file: str):
+    """The model family that a configuration names under ``"family"``:
+    ``bench/families/<family>.py``.  There is no default, so that a new
+    model is never read as another.  A family file defines:
+
+    * ``arch(conf) -> dict``: the shapes that the counts and the traffic
+      read, from the configuration's published keys; ``layers`` (the
+      attention layers, each doing the per-layer work below) and
+      ``vocab`` among them;
+    * ``model_config(conf)``: the program's ``ModelConfig``;
+    * ``make_weights(arch, dtype, seed)``: random weights in the serving
+      engine's parameter layout, made on the device in one jitted call;
+    * ``reference_logits(params, arch, anchor, prompt, served,
+      control=False)``: the plain f32 forward at ``highest`` precision
+      that ``correct`` is decided against, importing nothing of the
+      program; with ``control``, the float8 control
+      (``bench/reference.py``);
+    * the work counts, which the per-layer readers call through
+      ``ctx.family``: ``prefill_flops(n, arch, anchor)`` and
+      ``decode_flops(ctx, arch)`` of the whole model;
+      ``sparse_attention_work(n, arch, anchor)`` and
+      ``decode_attention_work(ctx, arch)``, (flops, bytes) of one layer;
+      and ``wave_work(attrs, arch, anchor)``, (flops, bytes) of the
+      sparse attention that one prefill wave kept, every layer, from the
+      attributes of its ``engine.prefill`` span.
+    """
+    name = conf.get("family")
+    if name is None:
+        raise SystemExit(f"configuration {conf_file} has no 'family' key: "
+                         f"name its model family, a file under {families_dir}")
+    path = families_dir / f"{name}.py"
+    if not (re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", str(name))
+            and path.is_file()):
+        raise SystemExit(f"configuration {conf_file} names 'family' {name!r}, "
+                         f"but there is no family file {path}")
+    return _load_module(path, f"bench_family_{name}")
+
+
 def load_metric(metrics_dir: Path, name: str):
     """The reader of one per-layer metric: ``bench/metrics/<name>.py``,
     with ``read(ctx) -> float | None`` and, optionally, ``KERNELS``, the
     kernel-name prefixes whose device time it needs from the trace."""
-    path = metrics_dir / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_module(metrics_dir / f"{name}.py", f"bench_metric_{name}")
 
 
 def reported_per_layer(bench: dict, cell: dict) -> list[dict]:
@@ -286,12 +330,11 @@ def sample_requests(reqs: list[Req], k: int, seed: int) -> list[Req]:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def compare(params, arch, anchor, sample, control=False) -> dict:
-    """Widest gap by which a served token's logit lies below the
+def compare(family, params, arch, anchor, sample, control=False) -> dict:
+    """Widest gap by which a served token's logit lies below the family
     reference's best, over the sample (or, with ``control``, the gap of
     the token that the float8 control ranks first)."""
-    from bench.reference import reference_logits
-
+    reference_logits = family.reference_logits
     worst, n = 0.0, 0
     for r in sample:
         served = np.asarray(r.r.generated, np.int32)
@@ -309,12 +352,12 @@ def compare(params, arch, anchor, sample, control=False) -> dict:
     return {"max_logit_gap": worst, "tokens_compared": n}
 
 
-def check(params, arch, anchor, reqs, limits: dict, seed: int,
+def check(family, params, arch, anchor, reqs, limits: dict, seed: int,
           control: bool = False) -> tuple[bool, dict, list]:
     """The decision a run prints as ``correct``: the sample's widest
     logit gap and the tokens compared, each against its limit."""
     sample = sample_requests(reqs, int(limits["sample_requests"]), seed)
-    got = compare(params, arch, anchor, sample, control=control)
+    got = compare(family, params, arch, anchor, sample, control=control)
     checks = {
         "max_logit_gap": {"value": got["max_logit_gap"],
                           "limit": limits["max_logit_gap"]},
@@ -342,7 +385,7 @@ def main(argv, t0_wall: float) -> int:
     args = parse_args(argv)
     start_wall = min(process_start_wall(t0_wall), t0_wall)
     c = load_cell(Path(args.benchmark), args.workload)
-    cell, conf, limits = c["cell"], c["conf"], c["limits"]
+    cell, conf, limits, family = c["cell"], c["conf"], c["limits"], c["family"]
 
     if not args.dry_run:
         enable_compile_cache()
@@ -361,10 +404,10 @@ def main(argv, t0_wall: float) -> int:
     compiles = CompileCounter()
 
     # ---- set-up ----
-    mcfg = build.model_config(conf)
-    arch, anchor = build.arch_of(conf), build.anchor_of(conf)
-    params = build.make_weights(arch, conf.get("torch_dtype", "bfloat16"),
-                                args.seed)
+    mcfg = family.model_config(conf)
+    arch, anchor = family.arch(conf), build.anchor_of(conf)
+    params = family.make_weights(arch, conf.get("torch_dtype", "bfloat16"),
+                                 args.seed)
     jax.block_until_ready(params)
     build.check_layout(params, mcfg)
     t_weights = time.time() - start_wall
@@ -393,8 +436,9 @@ def main(argv, t0_wall: float) -> int:
 
     # ---- check ----
     t_check = time.perf_counter()
-    correct, checks, sample = check(params, arch, anchor, rec["requests"],
-                                    limits, args.seed, control=args.control)
+    correct, checks, sample = check(family, params, arch, anchor,
+                                    rec["requests"], limits, args.seed,
+                                    control=args.control)
     t_check = time.perf_counter() - t_check
 
     in_window = [r for r in rec["requests"] if r.t_submit <= rec["t1"]]
@@ -406,6 +450,7 @@ def main(argv, t0_wall: float) -> int:
 
     # ---- metrics ----
     if args.trace:
+        from bench import spans
         from bench import trace as trace_lib
         from bench.context import RunContext
 
@@ -416,9 +461,10 @@ def main(argv, t0_wall: float) -> int:
             kernels = tuple(dict.fromkeys(
                 k for _, mod in readers for k in getattr(mod, "KERNELS", ())))
             planes = trace_lib.load_xplane(trace_lib.find_xplane(str(trace_dir)))
-            red = trace_lib.reduce_trace(planes, kernels=kernels)
+            red = trace_lib.reduce_trace(planes, kernels=kernels,
+                                         bench_spans=spans.bench_spans())
             shutil.rmtree(trace_dir.parent, ignore_errors=True)
-        ctx = RunContext(rec, arch, anchor, peaks, red)
+        ctx = RunContext(rec, family, arch, anchor, peaks, red)
         values = {}
         for m, mod in readers:
             v = mod.read(ctx)

@@ -1,5 +1,5 @@
 """Roofline bound of the decode attention of the tokens decoded in the
-window (every cached position, every layer; ``bench/counts.py``) over
+window (every cached position, every layer; counted by the family) over
 the summed device time of the ``paged_flash_decode`` kernel events."""
 
 from bench import counts
@@ -12,6 +12,6 @@ def read(ctx):
     if not t or not ctx.decode_ctx:
         return None
     bound = sum(counts.roofline_seconds(
-        *counts.decode_attention_work(c, ctx.arch), ctx.peaks)
+        *ctx.family.decode_attention_work(c, ctx.arch), ctx.peaks)
         for c in ctx.decode_ctx) * ctx.arch["layers"]
     return 100.0 * bound / t

@@ -1,8 +1,9 @@
-"""Plain f32 reference of the served path, with AnchorAttention's
-semantics, in blocks so that it fits at 32k tokens.
+"""AnchorAttention's semantics in plain f32, in blocks so that they fit at
+32k tokens: the attention part of every family's reference
+(``bench/families/<family>.py``), which builds the rest of its model's
+forward around these functions.
 
-It imports nothing of the program.  It follows the configuration: a
-SwiGLU decoder with RMSNorm and half-split RoPE; prompt rows attend as
+It imports nothing of the program.  Prompt rows attend as
 AnchorAttention specifies (paper Algorithms 1-3, as
 ``kernels/ref.py::anchor_attention_ref`` writes them for one head):
 
@@ -18,11 +19,12 @@ AnchorAttention specifies (paper Algorithms 1-3, as
 * a row attends, by one softmax, to its anchor region and the stripes.
 
 Rows past the prompt (the served tokens fed back) attend densely to
-every earlier position, as decoding does.  Everything is f32 with
-matmuls at ``highest`` precision.  With ``control=True`` every matmul
-operand is first rounded to float8 (e4m3, one scale per tensor), the
-precision below the configuration's bf16: the control that the
-comparison has to reject.
+every earlier position, as decoding does.  Everything is f32, and the
+families run it with matmuls at ``highest`` precision.  With
+``control=True`` every matmul operand is first rounded to float8 (e4m3,
+one scale per tensor), the precision below the configurations' bf16:
+the control that the comparison has to reject.  Keys and values may
+differ in width; ``d`` below is the query and key width.
 """
 
 from __future__ import annotations
@@ -32,12 +34,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 F32 = jnp.float32
 NEG = -1e30
 ROWS = 256  # query rows per attention block
-FFN_ROWS = 4096  # token rows per MLP block
 
 
 def _fp8(x):
@@ -46,35 +46,30 @@ def _fp8(x):
     return (x / scale).astype(jnp.float8_e4m3fn).astype(F32) * scale
 
 
-def _mm(control: bool, eq: str, a, b):
+def matmul(control: bool, eq: str, a, b):
+    """``einsum(eq, a, b)`` in f32, its operands first rounded to float8
+    for the control."""
     if control:
         a, b = _fp8(a), _fp8(b)
     return jnp.einsum(eq, a.astype(F32), b.astype(F32))
 
 
-def _rms(x, scale, eps):
+def rms_norm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
-    d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
-    ang = pos[:, None].astype(F32) * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _anchor_prompt(qh, kh, vh, n, an, control):
+def anchor_prompt(qh, kh, vh, n, an, control):
     """AnchorAttention over the prompt rows ``[0, n)``.  qh: (Hkv, G, T,
-    D); kh, vh: (Hkv, T, D).  Rows at or past ``n`` come out as zeros."""
+    D); kh: (Hkv, T, D); vh: (Hkv, T, Dv).  Rows at or past ``n`` come
+    out as zeros."""
     hkv, g, t, d = qh.shape
+    dv = vh.shape[-1]
     bq, bkv, step, cap = an["block_q"], an["block_kv"], an["step"], an["capacity"]
     sb = bq * step
     r = bq // bkv
     scale = d ** -0.5
     cols = jnp.arange(t)
-    mm = functools.partial(_mm, control)
+    mm = functools.partial(matmul, control)
     rb = math.gcd(ROWS, sb)
 
     def superblock(kb):
@@ -130,90 +125,26 @@ def _anchor_prompt(qh, kh, vh, n, an, control):
                     + mm("hgqk,hkd->hgqd", pw, vw)
                     + mm("hgqc,hgcd->hgqd", ps, vs))
 
-        out = jax.lax.map(attend, jnp.arange(sb // rb))  # (c, Hkv, G, R, D)
-        return jnp.moveaxis(out, 0, 2).reshape(hkv, g, sb, d)
+        out = jax.lax.map(attend, jnp.arange(sb // rb))  # (c, Hkv, G, R, Dv)
+        return jnp.moveaxis(out, 0, 2).reshape(hkv, g, sb, dv)
 
-    out = jax.lax.map(superblock, jnp.arange(t // sb))  # (T/sb, Hkv, G, sb, D)
-    return jnp.moveaxis(out, 0, 2).reshape(hkv, g, t, d)
+    out = jax.lax.map(superblock, jnp.arange(t // sb))  # (T/sb, Hkv, G, sb, Dv)
+    return jnp.moveaxis(out, 0, 2).reshape(hkv, g, t, dv)
 
 
-def _dense_rows(qh, kh, vh, n, rows, control):
+def dense_rows(qh, kh, vh, n, rows, control):
     """Dense causal attention of the ``rows`` rows starting at ``n``."""
     hkv, g, t, d = qh.shape
     q = jax.lax.dynamic_slice_in_dim(qh, n, rows, axis=2)
     r = n + jnp.arange(rows)
-    s = _mm(control, "hgqd,hkd->hgqk", q, kh) * d ** -0.5
+    s = matmul(control, "hgqd,hkd->hgqk", q, kh) * d ** -0.5
     s = jnp.where(jnp.arange(t)[None] <= r[:, None], s, NEG)
     p = jax.nn.softmax(s, axis=-1)
-    return _mm(control, "hgqk,hkd->hgqd", p, vh)
-
-
-@functools.partial(jax.jit, static_argnames=("arch", "anchor", "rows", "control"))
-def _layer(x, blocks, i, n, *, arch, anchor, rows, control):
-    a, an = dict(arch), dict(anchor)
-    lw = jax.tree.map(lambda w: w[i].astype(F32), blocks)
-    t = x.shape[0]
-    hq, hkv, d = a["heads"], a["kv_heads"], a["head_dim"]
-    g = hq // hkv
-    mm = functools.partial(_mm, control)
-    pos = jnp.arange(t)
-    h = _rms(x, lw["norm_mixer"]["scale"], a["norm_eps"])
-    q = _rope(mm("td,de->te", h, lw["attn"]["wq"]).reshape(t, hq, d), pos,
-              a["rope_theta"])
-    k = _rope(mm("td,de->te", h, lw["attn"]["wk"]).reshape(t, hkv, d), pos,
-              a["rope_theta"])
-    v = mm("td,de->te", h, lw["attn"]["wv"]).reshape(t, hkv, d)
-    qh = q.reshape(t, hkv, g, d).transpose(1, 2, 0, 3)
-    kh, vh = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
-    o = _anchor_prompt(qh, kh, vh, n, an, control)
-    o = jax.lax.dynamic_update_slice_in_dim(
-        o, _dense_rows(qh, kh, vh, n, rows, control), n, axis=2)
-    o = o.transpose(2, 0, 1, 3).reshape(t, hq * d)
-    x = x + mm("te,ed->td", o, lw["attn"]["wo"])
-
-    def ffn(xc):
-        hc = _rms(xc, lw["norm_ffn"]["scale"], a["norm_eps"])
-        gate = jax.nn.silu(mm("td,df->tf", hc, lw["mlp"]["wg"]))
-        up = mm("td,df->tf", hc, lw["mlp"]["wi"])
-        return xc + mm("tf,fd->td", gate * up, lw["mlp"]["wo"])
-
-    return jax.lax.map(ffn, x.reshape(-1, math.gcd(FFN_ROWS, t), x.shape[-1])
-                       ).reshape(x.shape)
-
-
-@functools.partial(jax.jit, static_argnames=("arch", "rows", "control"))
-def _head(x, final, head, n, *, arch, rows, control):
-    xs = jax.lax.dynamic_slice_in_dim(x, n - 1, rows + 1, axis=0)
-    h = _rms(xs, final.astype(F32), dict(arch)["norm_eps"])
-    return _mm(control, "td,vd->tv", h, head)
+    return matmul(control, "hgqk,hkd->hgqd", p, vh)
 
 
 def padded_rows(n_total: int, anchor: dict) -> int:
-    """Rows the reference runs: a whole number of superblocks, at least
+    """Rows a reference runs: a whole number of superblocks, at least
     two, with room for the window slice of superblock 0."""
     sb = anchor["block_q"] * anchor["step"]
     return max(2 * sb, -(-n_total // sb) * sb)
-
-
-def reference_logits(params: dict, arch: dict, anchor: dict,
-                     prompt: np.ndarray, served: np.ndarray,
-                     control: bool = False) -> np.ndarray:
-    """Logits (len(served), vocab) that predict each served token: at the
-    prompt's last position, then at each served token fed back but the
-    last."""
-    n, rows = len(prompt), len(served) - 1
-    toks = np.concatenate([prompt, served[:-1]]).astype(np.int32)
-    t = padded_rows(len(toks), anchor)
-    toks = np.pad(toks, (0, t - len(toks)))
-    akey = tuple(sorted(arch.items()))
-    nkey = tuple(sorted(anchor.items()))
-    with jax.default_matmul_precision("highest"):
-        x = params["embed"][jnp.asarray(toks)].astype(F32)
-        n_j = jnp.asarray(n, jnp.int32)
-        for i in range(arch["layers"]):
-            x = _layer(x, params["blocks"]["l0"], jnp.asarray(i), n_j,
-                       arch=akey, anchor=nkey, rows=rows, control=control)
-        head = params.get("lm_head", params["embed"])
-        logits = _head(x, params["final_norm"]["scale"], head, n_j,
-                       arch=akey, rows=rows, control=control)
-    return np.asarray(logits)

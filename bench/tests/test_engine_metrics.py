@@ -32,7 +32,7 @@ def _ctx(steps=(), stats0=None, stats1=None, log=None, trace_=None,
          arch=ARCH, anchor=ANCHOR, peaks_=PEAKS):
     rec = {"t0": 0.0, "t_close": 10.0, "steps": list(steps),
            "stats0": stats0 or {}, "stats1": stats1 or {}, "compiles": []}
-    ctx = RunContext(rec, arch, anchor, peaks_, trace_)
+    ctx = RunContext(rec, tiny.load_family(), arch, anchor, peaks_, trace_)
     if log is not None:
         ctx.spans = log
     return ctx
@@ -198,7 +198,8 @@ def test_earlier_readers_read_as_before():
                 "decode_ctx": [24579], "decode_calls": 2},
                {"t0": 1.9, "t1": 2.2, "prefill_lens": [],
                 "decode_ctx": [24580], "decode_calls": 1}]}
-    ctx = RunContext(rec, build.arch_of(conf), build.anchor_of(conf),
+    fam = tiny.load_family()
+    ctx = RunContext(rec, fam, fam.arch(conf), build.anchor_of(conf),
                      peaks.peaks_for("TPU v5 lite"), red)
     got = {name: _read(name, ctx) for name in PINNED}
     assert got == pytest.approx(PINNED)

@@ -11,16 +11,53 @@ def tiny_bench(tmp_path_factory):
     return tiny.write(tmp_path_factory.mktemp("bench"))
 
 
-def test_additions_are_data(tiny_bench, capsys):
-    res = tiny.run(capsys, tiny_bench, trace=1)
+def _files(root):
+    return {p.relative_to(root): p.read_bytes()
+            for p in sorted(root.rglob("*.py")) if "__pycache__" not in p.parts}
+
+
+@pytest.mark.parametrize("family", [None, "recorded_gqa"],
+                         ids=["dense_gqa", "new_family"])
+def test_additions_are_data(tmp_path, capsys, family):
+    before = _files(tiny.ROOT / "bench")
+    bench = tiny.write(tmp_path, family=family)
+    res = tiny.run(capsys, bench, trace=1)
     assert res["correct"] is True
     assert res["attempted"] >= 3 and res["failed"] == 0
-    # A dry run never prints device metrics; the added reader still ran.
+    # A dry run never prints device metrics; the added readers still ran.
     assert res["metrics"] == {}
     assert res["dry_layer_metrics"]["requests_prefilled"] > 0
+    assert res["dry_layer_metrics"]["work_counted"] > 0
     assert res["device"]["platform"] == "cpu"
     assert list(res)[-1] == "checks"
     assert res["checks"]["tokens_compared"]["value"] >= 24
+    if family is not None:
+        # The new family file alone took the model: its builder, its
+        # reference and its counts ran, and no file there before changed.
+        assert tiny.calls(bench, family) == set(tiny.CALLED)
+        copied = _files(tmp_path / "bench")
+        assert {p: copied[p] for p in before if p in copied} == {
+            p: v for p, v in before.items() if p in copied}
+    assert _files(tiny.ROOT / "bench") == before
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family", "../metrics/x"],
+                         ids=["missing_key", "missing_file", "outside"])
+def test_a_configuration_must_name_its_family_file(tmp_path, capsys, family):
+    from bench.harness import main
+
+    config = dict(tiny.CONFIG)
+    del config["family"]
+    if family is not None:
+        config["family"] = family
+    bench = tiny.write(tmp_path, config=config)
+    with pytest.raises(SystemExit) as e:
+        main(["--workload", "tiny.tinydoc", "--seed", "1", "--seconds", "1",
+              "--trace", "0", "--benchmark", str(bench), "--dry-run"], 0.0)
+    msg = str(e.value.code)
+    assert "'family'" in msg and "bench/configs/tiny.json" in msg
+    assert str(tmp_path / "bench" / "families") in msg
+    assert capsys.readouterr().out == ""
 
 
 def test_same_seed_same_inputs():

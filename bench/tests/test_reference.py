@@ -1,6 +1,10 @@
 """The blocked f32 reference against the one-head dense oracle of
 AnchorAttention, the program's varlen and capacity semantics, and the
-engine's logits on the xla backend, at small sizes on the CPU."""
+engine's logits on the xla backend, at small sizes on the CPU; and the
+``dense_gqa`` family's reference against logits recorded before it
+moved into the family file."""
+
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -10,6 +14,8 @@ import jax.numpy as jnp
 
 from bench import build, reference
 from bench.tests import tiny
+
+GOLDEN = Path(__file__).parent / "data" / "golden_reference.npz"
 
 N, D = 256, 16
 
@@ -30,7 +36,7 @@ def _qkv(seed, hq=1, hkv=1, n=N):
 def _ours(q, k, v, n, an):
     hkv = k.shape[0]
     qh = q.reshape(hkv, q.shape[0] // hkv, q.shape[1], D)
-    return reference._anchor_prompt(qh, k, v, n, an, False).reshape(q.shape)
+    return reference.anchor_prompt(qh, k, v, n, an, False).reshape(q.shape)
 
 
 @pytest.mark.parametrize("theta", [0.5, 2.0, 12.0])
@@ -64,13 +70,27 @@ def test_matches_the_programs_varlen_and_capacity(n, capacity):
     assert float(jnp.abs(got[:, n:]).sum()) == 0.0
 
 
+def test_values_may_differ_in_width_from_keys():
+    # Each output column depends on its value column alone, so values of
+    # twice the key width give the two halves' outputs side by side.
+    q, k, v = _qkv(2, hq=4, hkv=2)
+    v2 = jnp.concatenate([v, 3.0 * v[:, :, ::-1]], axis=-1)
+    qh = q.reshape(2, 2, N, D)
+    wide = reference.anchor_prompt(qh, k, v2, 200, _an(2.0, 4), False)
+    halves = [reference.anchor_prompt(qh, k, x, 200, _an(2.0, 4), False)
+              for x in (v2[..., :D], v2[..., D:])]
+    assert wide.shape == (2, 2, N, 2 * D)
+    np.testing.assert_allclose(wide, jnp.concatenate(halves, -1), atol=1e-5)
+
+
 def test_matches_the_engines_logits():
     from repro.serving import engine as engine_lib
 
+    fam = tiny.load_family()
     conf = dict(tiny.CONFIG, torch_dtype="float32")
-    arch, anchor = build.arch_of(conf), build.anchor_of(conf)
-    mcfg = build.model_config(conf)
-    params = build.make_weights(arch, "float32", 5)
+    arch, anchor = fam.arch(conf), build.anchor_of(conf)
+    mcfg = fam.model_config(conf)
+    params = fam.make_weights(arch, "float32", 5)
     eng = build.make_engine(params, mcfg, conf, backend="xla")
     seen = []
     prefill = engine_lib.model_lib.prefill
@@ -95,7 +115,24 @@ def test_matches_the_engines_logits():
     finally:
         engine_lib.model_lib.prefill = prefill
     served = np.asarray(done[0].generated, np.int32)
-    ref = reference.reference_logits(params, arch, anchor, prompt, served)
+    ref = fam.reference_logits(params, arch, anchor, prompt, served)
     got = np.stack(seen[:len(served)])
     assert np.abs(got - ref).max() < 1e-3 * np.abs(ref).max()
     assert (ref.argmax(-1) == served).all()
+
+
+@pytest.mark.parametrize("control", [False, True], ids=["plain", "control"])
+def test_family_reference_is_bitwise_the_recorded_one(control):
+    # Recorded from the reference before it moved into
+    # ``bench/families/dense_gqa.py``: bf16 weights of seed 5, a 90-token
+    # prompt and 6 served tokens drawn from seed 0.
+    fam = tiny.load_family()
+    conf = tiny.CONFIG
+    arch, anchor = fam.arch(conf), build.anchor_of(conf)
+    params = fam.make_weights(arch, "bfloat16", 5)
+    rec = np.load(GOLDEN)
+    got = fam.reference_logits(params, arch, anchor, rec["prompt"],
+                               rec["served"], control=control)
+    want = rec["control" if control else "plain"]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(got, want)
